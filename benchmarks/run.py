@@ -30,6 +30,8 @@ MODULES = [
 
 
 def main():
+    from repro.launch.persistent_cache import enable_persistent_cache
+    enable_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,

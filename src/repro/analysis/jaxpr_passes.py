@@ -42,10 +42,10 @@ BAD_DTYPES = ("float64", "int64", "uint64", "complex128")
 
 def _subjaxprs(value):
     """Yield every Jaxpr hiding in an eqn param value."""
-    import jax
-    if isinstance(value, jax.core.ClosedJaxpr):
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, Jaxpr):
         yield value
     elif isinstance(value, (list, tuple)):
         for v in value:

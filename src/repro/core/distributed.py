@@ -38,18 +38,14 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import visit as _visit
 from repro.core.graph import BlockGraph
 from repro.core.visit import EDGE_SHIFT, VisitAlgebra
 from repro.core.yielding import YieldConfig
 
-if hasattr(jax, "shard_map"):                      # jax >= 0.6
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:                                              # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _experimental_sm
-    _shard_map = functools.partial(_experimental_sm, check_rep=False)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 @dataclasses.dataclass
@@ -159,15 +155,22 @@ def _make_program(algebra: VisitAlgebra, mesh: Mesh, *, pl: int, dmax: int,
         elo = jax.lax.psum(elo, part_axis)
         return jnp.stack(planes), buf, ehi, elo, steps
 
-    graph_specs = (P(part_axis),) * 5
+    in_specs = _program_specs(query_axes, part_axis)
+    vals_spec, state_spec, q_spec = in_specs[5:8]
+    return jax.jit(_shard_map(
+        stepper, mesh=mesh, in_specs=in_specs,
+        out_specs=(vals_spec, state_spec, q_spec, q_spec, P()),
+    ))
+
+
+def _program_specs(query_axes: Tuple[str, ...], part_axis: str):
+    """In-specs of the mesh program: five graph slabs split over the
+    partition axis, value planes / buffer split over partitions and
+    queries, the (hi, lo) edge counters split over queries."""
     state_spec = P(*((part_axis,) + tuple(query_axes) + (None,)))
     vals_spec = P(*((None, part_axis) + tuple(query_axes) + (None,)))
     q_spec = P(*query_axes)
-    return jax.jit(_shard_map(
-        stepper, mesh=mesh,
-        in_specs=graph_specs + (vals_spec, state_spec, q_spec, q_spec),
-        out_specs=(vals_spec, state_spec, q_spec, q_spec, P()),
-    ))
+    return (P(part_axis),) * 5 + (vals_spec, state_spec, q_spec, q_spec)
 
 
 def _check_query_sharding(Q: int, mesh: Mesh, query_axes) -> int:
@@ -203,14 +206,20 @@ def _run_program(algebra: VisitAlgebra, bg: BlockGraph, sources: np.ndarray,
     fn = _make_program(algebra, mesh, pl=pl, dmax=dmax, ndev=ndev,
                        max_rounds=max_rounds, max_supersteps=max_supersteps,
                        query_axes=tuple(query_axes), part_axis=part_axis)
-    vals, buf, ehi, elo, steps = fn(
-        sg.blocks.reshape(p_pad, 1 + dmax, B, B),
-        sg.dst_part.reshape(p_pad, 1 + dmax),
-        sg.row_nnz.reshape(p_pad, 1 + dmax, B),
-        sg.deg.reshape(p_pad, B),
-        sg.edge_budget.reshape(p_pad),
-        np.stack(planes0), buf0,
-        np.zeros((Q,), dtype=np.int32), np.zeros((Q,), dtype=np.int32))
+    host = (sg.blocks.reshape(p_pad, 1 + dmax, B, B),
+            sg.dst_part.reshape(p_pad, 1 + dmax),
+            sg.row_nnz.reshape(p_pad, 1 + dmax, B),
+            sg.deg.reshape(p_pad, B),
+            sg.edge_budget.reshape(p_pad),
+            np.stack(planes0), buf0,
+            np.zeros((Q,), dtype=np.int32), np.zeros((Q,), dtype=np.int32))
+    # each device receives only its own slab: placed explicitly on the
+    # mesh, never staged whole on the first device
+    args = [jax.device_put(x, NamedSharding(mesh, spec)) for x, spec in
+            zip(host, _program_specs(tuple(query_axes), part_axis))]
+    del host, sg
+    vals, buf, ehi, elo, steps = fn(*args)
+    del args
     edges = (np.asarray(ehi, dtype=np.float64) * float(1 << EDGE_SHIFT)
              + np.asarray(elo, dtype=np.float64))
     return np.asarray(vals), np.asarray(buf), edges, int(np.asarray(steps))

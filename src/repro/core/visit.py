@@ -316,9 +316,14 @@ def _make_visit_body(dg, algebra: VisitAlgebra, max_rounds: int) -> Callable:
     """
     P = dg.num_parts
 
+    def block(k):
+        # one dynamic slice per block: a gather over the block store lets
+        # XLA split (and so copy) the whole store inside the visit loop
+        return jax.lax.dynamic_index_in_dim(dg.blocks, k, keepdims=False)
+
     def visit(state: VisitState, p: jax.Array, counter: jax.Array):
         kd = dg.diag_blk[p]
-        w_pp, nnz_pp, deg_p = dg.blocks[kd], dg.row_nnz[kd], dg.deg[p]
+        w_pp, nnz_pp, deg_p = block(kd), dg.row_nnz[kd], dg.deg[p]
         planes_row = tuple(x[p] for x in state.planes)
         buf_row = state.buf[p]
         carry0 = algebra.begin(planes_row, buf_row, deg_p)
@@ -351,8 +356,9 @@ def _make_visit_body(dg, algebra: VisitAlgebra, max_rounds: int) -> Callable:
         blk0 = jnp.where(valid, dg.nbr_blk[p], 0)
         j0 = jnp.where(valid, parts, 0)                # clamped gather index
         jj = jnp.where(valid, parts, P)                # trash row for padding
+        w_nb = jnp.stack([block(blk0[s]) for s in range(blk0.shape[0])])
         cands = jax.vmap(lambda w: algebra.contrib(payload, w))(
-            dg.blocks[blk0])                           # [dmax, Q, B]
+            w_nb)                                      # [dmax, Q, B]
         cands = jnp.where(valid[:, None, None], cands, algebra.identity)
         nnz_sl = jnp.where(valid[:, None], dg.row_nnz[blk0], 0)  # [dmax, B]
         eq = eq + jnp.sum(jnp.where(emask[None], nnz_sl[:, None, :], 0),
@@ -385,12 +391,53 @@ def _make_visit_body(dg, algebra: VisitAlgebra, max_rounds: int) -> Callable:
     return visit
 
 
+class GraphBound:
+    """A jitted program with the device graph bound as its first argument.
+
+    The graph's arrays ride as operands, never as constants closed over by
+    the trace: a closed-over array is embedded in the program text, which
+    at road-network size is gigabytes of HLO.  Calling, ``lower(...)`` and
+    ``lower(...).compile()`` all take the remaining arguments only, so a
+    bound program and its AOT-compiled executable share one calling
+    convention.
+    """
+
+    def __init__(self, fn, graph):
+        self.fn = fn
+        self.graph = graph
+
+    def __call__(self, *args):
+        return self.fn(self.graph, *args)
+
+    def lower(self, *args):
+        return _BoundLowered(self.fn.lower(self.graph, *args), self.graph)
+
+    def __getattr__(self, name):
+        return getattr(self.__dict__["fn"], name)
+
+
+class _BoundLowered:
+    def __init__(self, lowered, graph):
+        self.lowered = lowered
+        self.graph = graph
+
+    def compile(self) -> GraphBound:
+        return GraphBound(self.lowered.compile(), self.graph)
+
+    def __getattr__(self, name):
+        return getattr(self.__dict__["lowered"], name)
+
+
 def make_visit(dg, algebra: VisitAlgebra, max_rounds: int) -> Callable:
     """The one visit kernel, jitted for per-visit host dispatch.
 
     ``visit(state, p, counter) -> (state', (rounds, eq))``.
     """
-    return jax.jit(_make_visit_body(dg, algebra, max_rounds))
+    @jax.jit
+    def visit(g, state, p, counter):
+        return _make_visit_body(g, algebra, max_rounds)(state, p, counter)
+
+    return GraphBound(visit, dg)
 
 
 # ---------------------------------------------------------------------------
@@ -486,8 +533,6 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
         # the dispatch-table wiring of the three visit kernels (the
         # pallas.reachability pass keys off these imports)
         from repro.kernels.frontier.ops import frontier_tile
-        from repro.kernels.fused_visit.fused import (META_OPS, META_PRIO,
-                                                     META_STAMP)
         from repro.kernels.fused_visit.ops import make_fused_visit
         from repro.kernels.ppr_push.ops import push_tile
         fv = make_fused_visit(dg, algebra, max_rounds,
@@ -496,10 +541,14 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
 
         # the while_loop carries the kernel's packed layout for the whole
         # K-visit chunk: pack once on entry, unpack once on exit, and read
-        # the scheduler metadata straight out of the packed planes.
-        def visit(pk, p, counter):
-            pk, rounds, eq = fv.visit(pk, p, counter)
-            return pk, (rounds, eq)
+        # the scheduler metadata straight out of the packed tables.
+        def visit_of(g):
+            fg = fv.graph(g)
+
+            def visit(pk, p, counter):
+                pk, rounds, eq = fv.visit(fg, pk, p, counter)
+                return pk, (rounds, eq)
+            return visit
 
         def enter(state: VisitState):
             return fv.pack(state.planes, state.buf, state.prio,
@@ -509,23 +558,24 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
             return VisitState(*fv.unpack(pk))
 
         def meta(pk):
-            prio = jax.lax.bitcast_convert_type(pk.meta[:P, META_PRIO],
-                                                jnp.float32)
-            return prio, pk.meta[:P, META_STAMP], pk.meta[:P, META_OPS]
+            return pk.prio[:P], pk.stamp[:P], pk.ops[:P]
     else:
         if frontier_mode != "dense":
             raise ValueError(
                 "frontier_mode is a fused-kernel switch; the XLA megastep "
                 "always runs the dense frontier math")
-        visit = _make_visit_body(dg, algebra, max_rounds)
+
+        def visit_of(g):
+            return _make_visit_body(g, algebra, max_rounds)
         enter = leave = lambda st: st
 
         def meta(st: VisitState):
             return st.prio, st.stamp, st.ops_count
 
     @jax.jit
-    def megastep(state: VisitState, counter: jax.Array, limit: jax.Array,
-                 key: jax.Array):
+    def megastep(g, state: VisitState, counter: jax.Array,
+                 limit: jax.Array, key: jax.Array):
+        visit = visit_of(g)
         limit_k = jnp.minimum(jnp.int32(limit), jnp.int32(K))
 
         def cond(c):
@@ -558,13 +608,13 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
             cond, body, init)
         st = leave(st)
         lane_pending = (jnp.any(
-            algebra.pending(st.buf[:P], st.planes, dg.deg), axis=(0, 2))
+            algebra.pending(st.buf[:P], st.planes, g.deg), axis=(0, 2))
             if harvest_mask else jnp.zeros((0,), dtype=bool))
         return st, MegastepStats(visits=k, rounds=rounds, eq_hi=hi, eq_lo=lo,
                                  visit_counts=counts, order=order,
                                  lane_pending=lane_pending, key=key)
 
-    return megastep
+    return GraphBound(megastep, dg)
 
 
 def harvest_edges(eq_hi: np.ndarray, eq_lo: np.ndarray) -> np.ndarray:

@@ -119,12 +119,33 @@ class MemoryModel:
       dist/value tile   Q*B*dtype
       buffer tile       Q*B*dtype
     HBM holds the full block-sparse graph plus the [P, Q, B] state planes;
-    ``hbm_bytes`` caps the state so Q and B cannot silently overflow a chip.
+    ``hbm_bytes`` caps both so Q and B cannot silently overflow a chip.
+
+    The defaults are the explicit model of the CPU path (Pallas kernels
+    interpreted); :meth:`for_device` gives a TPU its measured row.
     """
     vmem_bytes: int = 96 * 1024 * 1024
     hbm_bytes: int = 16 * 1024 ** 3
     dtype_bytes: int = 4
     double_buffer: bool = True
+
+    @classmethod
+    def for_device(cls, device=None) -> "MemoryModel":
+        """The model of ``device`` (default: JAX's first device).
+
+        A TPU gets its ``kernels.tpu.TPU_MEMORY`` row — the VMEM limit
+        every kernel requests and the chip's HBM — and a TPU kind missing
+        from that table raises instead of guessing.  Any other backend
+        keeps the explicit defaults.
+        """
+        import jax
+
+        from repro.kernels.tpu import tpu_memory
+        device = device if device is not None else jax.devices()[0]
+        if device.platform != "tpu":
+            return cls()
+        row = tpu_memory(device.device_kind)
+        return cls(vmem_bytes=row.vmem_limit_bytes, hbm_bytes=row.hbm_bytes)
 
     def working_set(self, block_size: int, num_queries: int) -> int:
         mult = 2 if self.double_buffer else 1
@@ -137,22 +158,23 @@ class MemoryModel:
 
         The fused kernel keeps every state channel (``num_planes`` value
         planes + the buffer) for the visited partition in VMEM across the
-        whole visit, both the in and the aliased out block, plus the
-        partition's pre-gathered adjacency row (diagonal + ``dmax``
-        boundary blocks, each [B+1, B] with the nnz row folded in), the
-        degree row, the request vector, and the emission parking scratch
-        (two [Q, B] planes and a degree row per slot, slot 0 being the
-        resident row).  This is deliberately larger than ``working_set``:
-        residency across rounds is the fusion's point, so the planner
-        must budget the whole visit, not one relaxation.
+        whole visit, the in and the aliased out block each double-buffered
+        by the pipeline, plus the partition's adjacency row DMA'd into
+        scratch (diagonal + ``dmax`` boundary blocks of [B, B], and their
+        per-row edge counts, one 8-sublane tile each), the relax source
+        tile, and the emission parking scratch (two [Q, B] planes and a
+        degree row per slot, slot 0 being the resident row).  This is
+        deliberately larger than ``working_set``: residency across rounds
+        is the fusion's point, so the planner must budget the whole visit,
+        not one relaxation.
         """
         b, q, d = block_size, num_queries, self.dtype_bytes
         chans = num_planes + 1
         slots = 1 + dmax
-        state = 2 * chans * q * b * d            # in + aliased out block
-        adj = slots * (b + 1) * b * d            # w_vis row, nnz folded in
-        scratch = slots * (2 * q * b + b) * d    # cand/plane/deg parking
-        return state + adj + scratch + b * d + (1 + q) * d
+        state = 2 * 2 * chans * q * b * d        # in + out, double-buffered
+        adj = slots * (b + 8) * b * d            # blocks + nnz row tiles
+        scratch = slots * (2 * q + 8) * b * d    # cand/plane/deg parking
+        return state + adj + scratch + q * b * d + 2 * b * d
 
     def state_bytes(self, n_vertices: int, num_queries: int,
                     block_size: int) -> int:
@@ -187,14 +209,21 @@ class MemoryModel:
     def fits(self, block_size: int, num_queries: int,
              n_vertices: Optional[int] = None, *,
              fused: bool = False, num_planes: int = 2,
-             dmax: int = FUSED_DMAX_BUDGET) -> bool:
+             dmax: int = FUSED_DMAX_BUDGET,
+             adjacency_bytes: int = 0) -> bool:
+        """True if one visit fits VMEM and the device arrays fit HBM.
+
+        ``adjacency_bytes`` is the dense block store the plan stages next
+        to the state planes (:func:`model_block_size` estimates it from the
+        degree sequence); HBM must hold both."""
         if self.working_set(block_size, num_queries) > self.vmem_bytes:
             return False
         if fused and self.fused_working_set(
                 block_size, num_queries, num_planes, dmax) > self.vmem_bytes:
             return False
         if n_vertices is not None and self.state_bytes(
-                n_vertices, num_queries, block_size) > self.hbm_bytes:
+                n_vertices, num_queries, block_size) + int(
+                    adjacency_bytes) > self.hbm_bytes:
             return False
         return True
 
@@ -261,6 +290,17 @@ def est_dmax(g: CSRGraph, block_size: int) -> int:
                    np.ceil(top / max(float(block_size), 1.0))))
 
 
+def adjacency_estimate(g: CSRGraph, block_size: int,
+                       mem: MemoryModel) -> int:
+    """HBM bytes of the dense block store at ``block_size``, estimated
+    before partitioning: about one neighborhood (the diagonal block plus
+    :func:`est_dmax` boundary blocks) per partition.  Once a graph is
+    partitioned its ``BlockGraph.blocks.nbytes`` is the exact figure."""
+    b = int(block_size)
+    nparts = -(-g.n // b)
+    return nparts * (1 + est_dmax(g, b)) * b * b * mem.dtype_bytes
+
+
 def model_block_size(g: CSRGraph, num_queries: int, mem: MemoryModel,
                      candidates: Sequence[int] = CANDIDATE_BLOCK_SIZES,
                      min_parts: int = 8, fused: bool = False,
@@ -283,13 +323,14 @@ def model_block_size(g: CSRGraph, num_queries: int, mem: MemoryModel,
     """
     best = None
     for b in candidates:
-        if -(-g.n // b) < max(2, min(min_parts, g.n // candidates[0])):
+        nparts = -(-g.n // b)
+        if nparts < max(2, min(min_parts, g.n // candidates[0])):
             break
-        if degree_aware:
-            hood = (1 + est_dmax(g, b)) * b * b * mem.dtype_bytes
-            if hood > mem.vmem_bytes:
-                continue   # hub neighborhoods outgrow VMEM at this B
-        if mem.fits(b, num_queries, g.n, fused=fused):
+        hood = (1 + est_dmax(g, b)) * b * b * mem.dtype_bytes
+        if degree_aware and hood > mem.vmem_bytes:
+            continue   # hub neighborhoods outgrow VMEM at this B
+        if mem.fits(b, num_queries, g.n, fused=fused,
+                    adjacency_bytes=adjacency_estimate(g, b, mem)):
             best = b
     if best is None:
         raise ValueError(
@@ -347,7 +388,9 @@ def autotune_block_size(session, kind: str, sources: np.ndarray,
     g = session.graph
     nq = num_queries if num_queries is not None else len(sources)
     feasible = [b for b in candidates
-                if b < max(2, g.n) and mem.fits(b, nq, g.n)]
+                if b < max(2, g.n) and mem.fits(
+                    b, nq, g.n,
+                    adjacency_bytes=adjacency_estimate(g, b, mem))]
     if not feasible:
         raise ValueError(
             f"no candidate block size fits the memory model for Q={nq}; "
@@ -387,7 +430,8 @@ def result_cache_budget(mem: MemoryModel, n_vertices: int, block_size: int,
 def autoscale_capacity(queue_depth: int, active: int, *,
                        mem: MemoryModel, n_vertices: int, block_size: int,
                        min_capacity: int = 1,
-                       max_capacity: int = 1024) -> int:
+                       max_capacity: int = 1024,
+                       adjacency_bytes: int = 0) -> int:
     """Suggest a lane-pool ``capacity`` from observed queue pressure.
 
     The serving autoscaling hint (DESIGN.md §4.2): demand is what is
@@ -395,14 +439,16 @@ def autoscale_capacity(queue_depth: int, active: int, *,
     covering it (powers of two keep the set of jitted engine shapes
     logarithmic in demand), clamped to ``[min_capacity, max_capacity]`` and
     then shrunk until the §3.1 memory model accepts the visit working set
-    and the HBM state planes at the pool's block size.  Pure function of
+    and the HBM state planes next to the graph's ``adjacency_bytes`` of
+    staged blocks at the pool's block size.  Pure function of
     its inputs — GraphServer calls it between chunks and applies a changed
     suggestion only when the pool is idle, so resizing never moves an
     in-flight lane.
     """
     cap = pow2_bucket(int(queue_depth) + int(active),
                       min_capacity=min_capacity, max_capacity=max_capacity)
-    while cap > min_capacity and not mem.fits(block_size, cap, n_vertices):
+    while cap > min_capacity and not mem.fits(
+            block_size, cap, n_vertices, adjacency_bytes=adjacency_bytes):
         cap //= 2
     return int(cap)
 
@@ -420,17 +466,20 @@ def make_plan(g: CSRGraph, num_queries: int, *,
 
     ``FPPSession.plan(tune=True)`` upgrades the block size by measurement.
     ``fused="auto"`` defers the visit-body choice to the per-kind
-    yardsticks (:func:`auto_fused`); block sizing then budgets the fused
-    working set, the conservative bound, since some kinds may fuse.
-    ``degree_aware=False`` disables the hub-skew VMEM guard in
+    yardsticks (:func:`auto_fused`).  Block sizing always budgets the
+    fused working set, the conservative bound: whatever the plan's
+    default, a run may pick the fused body (``run(fused=True)``,
+    ``GraphServer``'s per-kind auto), and it must compile at the planned
+    B.  ``degree_aware=False`` disables the hub-skew VMEM guard in
     :func:`model_block_size` (ignored when ``block_size`` is explicit).
+    ``mem`` defaults to :meth:`MemoryModel.for_device`.
     """
-    mem = mem or MemoryModel()
+    mem = mem or MemoryModel.for_device()
     if fused not in (True, False, "auto"):
         raise ValueError(f"fused must be True, False, or 'auto', "
                          f"got {fused!r}")
     if block_size is None:
-        block_size = model_block_size(g, num_queries, mem, fused=bool(fused),
+        block_size = model_block_size(g, num_queries, mem, fused=True,
                                       degree_aware=degree_aware)
     method = method or default_method(g)
     return Plan(block_size=int(block_size), method=method, schedule=schedule,

@@ -55,7 +55,7 @@ class FPPSession:
 
     def __init__(self, g: CSRGraph, *, mem: Optional[MemoryModel] = None):
         self.graph = g
-        self.mem = mem or MemoryModel()
+        self.mem = mem or MemoryModel.for_device()
         self._plan: Optional[Plan] = None
         # (block_size, method, weight_variant) -> (BlockGraph, perm)
         self._prepared: Dict[tuple, Tuple[BlockGraph, np.ndarray]] = {}

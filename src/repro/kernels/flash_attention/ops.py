@@ -11,6 +11,7 @@ from repro.kernels.flash_attention.flash import (DEFAULT_KV_CHUNK,
                                                  DEFAULT_Q_TILE,
                                                  flash_attention_pallas_call)
 from repro.kernels.flash_attention.ref import flash_attention_ref
+from repro.kernels.tpu import on_tpu
 
 #: static contract (DESIGN.md §7): canonical bh=8, Sq=Skv=256, hd=64
 #: (a reduced-config prefill).  Not reachable from a dispatch table on
@@ -32,10 +33,6 @@ CONTRACTS = (
 )
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _pad_to(x, axis, mult):
     n = x.shape[axis]
     pad = (-n) % mult
@@ -54,7 +51,7 @@ def flash_attention(q, k, v, *, causal=True, window=None,
     q-rows of one kv-head's group against that head's KV.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     B, Sq, H, hd = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     g = H // Hkv

@@ -23,12 +23,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tpu import compiler_params, on_tpu
+
 DEFAULT_Q_TILE = 128
 INF = jnp.inf
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def frontier_tile(buf: jax.Array, dist: jax.Array, *, delta: float,
@@ -71,10 +69,10 @@ def frontier_pallas_call(buf, dist, *, delta: float,
                          interpret: Optional[bool] = None):
     """buf, dist: [Q, B] -> (d1 [Q, B], srcs [Q, B], prio_rows [Q]).
 
-    ``interpret=None`` follows the ``_on_tpu()`` autodetect the ``ops.py``
+    ``interpret=None`` follows the ``on_tpu()`` autodetect the ``ops.py``
     wrapper uses, so a direct call can't silently run interpreted on TPU."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     q, b = buf.shape
     qt = min(q_tile, q) if q % min(q_tile, q) == 0 else q
     grid = (q // qt,)
@@ -95,5 +93,6 @@ def frontier_pallas_call(buf, dist, *, delta: float,
             jax.ShapeDtypeStruct((q, b), buf.dtype),
             jax.ShapeDtypeStruct((q,), buf.dtype),
         ],
+        compiler_params=compiler_params(("parallel",)),
         interpret=interpret,
     )(buf, dist)

@@ -8,6 +8,7 @@ from repro.kernels.contract import KernelContract, TileSpec
 from repro.kernels.frontier.frontier import (frontier_pallas_call,
                                              frontier_tile)
 from repro.kernels.frontier.ref import frontier_ref
+from repro.kernels.tpu import on_tpu
 
 #: static contract (DESIGN.md §7): canonical B=64 instantiation, tiled
 #: q_tile=32 so the per-step footprint stays inside the planner model's
@@ -30,17 +31,13 @@ CONTRACTS = (
 __all__ = ["CONTRACTS", "frontier", "frontier_pallas", "frontier_tile"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def frontier(buf, dist, *, delta: float):
     return frontier_ref(buf, dist, delta=delta)
 
 
 def frontier_pallas(buf, dist, *, delta: float, interpret=None):
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     q, b = buf.shape
     pad = (-q) % 8
     if pad:

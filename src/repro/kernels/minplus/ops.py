@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from repro.kernels.contract import KernelContract, TileSpec
 from repro.kernels.minplus import minplus as _k
 from repro.kernels.minplus.ref import masked_matmul_ref, minplus_ref
+from repro.kernels.tpu import on_tpu
 
 #: static contracts (DESIGN.md §7): canonical instantiation at the
 #: planner's smallest block B=64 with a full Q=64 query tile (qt = min(
@@ -34,10 +35,6 @@ CONTRACTS = (
         out_tiles=(TileSpec("out", (64, 64), (64, 64)),),
         wired=True, block_size=64, num_queries=64),
 )
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def minplus(d: jax.Array, w: jax.Array) -> jax.Array:
@@ -63,7 +60,7 @@ def _pad_q(x: jax.Array, tile: int, identity: float):
 def minplus_pallas(d: jax.Array, w: jax.Array, q_tile: int = 128) -> jax.Array:
     dp, q = _pad_q(d, q_tile, jnp.inf)
     out = _k.minplus_pallas_call(dp, w, q_tile=q_tile,
-                                 interpret=not _on_tpu())
+                                 interpret=not on_tpu())
     return out[:q]
 
 
@@ -71,5 +68,5 @@ def masked_matmul_pallas(x: jax.Array, w: jax.Array,
                          q_tile: int = 128) -> jax.Array:
     xp, q = _pad_q(x, q_tile, 0.0)
     out = _k.masked_matmul_pallas_call(xp, w, q_tile=q_tile,
-                                       interpret=not _on_tpu())
+                                       interpret=not on_tpu())
     return out[:q]

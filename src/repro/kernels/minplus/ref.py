@@ -44,4 +44,6 @@ def minplus_ref(d: jax.Array, w: jax.Array, chunk: int = 128) -> jax.Array:
 def masked_matmul_ref(x: jax.Array, w: jax.Array) -> jax.Array:
     """PPR spread oracle: ``out[q, v] = sum_u x[q, u] * [w[u, v] finite]``."""
     mask = jnp.isfinite(w).astype(x.dtype)
-    return x @ mask
+    # full f32 contraction: TPU's default f32 matmul rounds x to bf16,
+    # an error far above the eps the push algebra guarantees
+    return jnp.dot(x, mask, precision=jax.lax.Precision.HIGHEST)

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 from repro.kernels.contract import KernelContract, TileSpec
 from repro.kernels.ppr_push.push import ppr_push_pallas_call, push_tile
 from repro.kernels.ppr_push.ref import ppr_push_ref
+from repro.kernels.tpu import on_tpu
 
 #: static contract (DESIGN.md §7): canonical B=64 instantiation, tiled
 #: q_tile=16 so the per-step footprint (three state planes in and out
@@ -33,10 +34,6 @@ CONTRACTS = (
 __all__ = ["CONTRACTS", "ppr_push", "ppr_push_pallas", "push_tile"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def ppr_push(p, r, acc, w, deg, *, alpha: float, eps: float):
     return ppr_push_ref(p, r, acc, w, deg, alpha=alpha, eps=eps)
 
@@ -44,7 +41,7 @@ def ppr_push(p, r, acc, w, deg, *, alpha: float, eps: float):
 def ppr_push_pallas(p, r, acc, w, deg, *, alpha: float, eps: float,
                     interpret=None):
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     if deg.ndim == 1:
         deg = deg[None, :]
     deg = deg.astype(p.dtype)

@@ -21,11 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tpu import compiler_params, on_tpu
+
 DEFAULT_Q_TILE = 128
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def push_tile(p: jax.Array, r: jax.Array, acc: jax.Array, w: jax.Array,
@@ -56,7 +54,8 @@ def push_tile(p: jax.Array, r: jax.Array, acc: jax.Array, w: jax.Array,
     push = (1.0 - alpha) * r * af / degc
     if spread is None:
         mask = jnp.isfinite(w).astype(r.dtype)
-        sp = jnp.dot(push, mask, preferred_element_type=r.dtype)
+        sp = jnp.dot(push, mask, precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=r.dtype)
     else:
         sp = spread(push, w)
     r1 = r * (1.0 - af) + sp
@@ -81,10 +80,10 @@ def ppr_push_pallas_call(p, r, acc, w, deg, *, alpha: float, eps: float,
                          interpret: Optional[bool] = None):
     """p, r, acc: [Q, B]; w: [B, B] (+inf absent); deg: [1, B] float.
 
-    ``interpret=None`` follows the ``_on_tpu()`` autodetect the ``ops.py``
+    ``interpret=None`` follows the ``on_tpu()`` autodetect the ``ops.py``
     wrapper uses, so a direct call can't silently run interpreted on TPU."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     q, b = p.shape
     qt = min(q_tile, q) if q % min(q_tile, q) == 0 else q
     grid = (q // qt,)
@@ -97,5 +96,6 @@ def ppr_push_pallas_call(p, r, acc, w, deg, *, alpha: float, eps: float,
                   pl.BlockSpec((1, b), lambda i: (0, 0))],
         out_specs=[tile, tile, tile],
         out_shape=[jax.ShapeDtypeStruct((q, b), p.dtype)] * 3,
+        compiler_params=compiler_params(("parallel",)),
         interpret=interpret,
     )(p, r, acc, w, deg)

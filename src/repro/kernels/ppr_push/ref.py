@@ -1,6 +1,7 @@
 """Pure-jnp oracle for the fused PPR push kernel."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -12,6 +13,6 @@ def ppr_push_ref(p, r, acc, w, deg, *, alpha: float, eps: float):
     p_out = p + alpha * r * af
     push = (1.0 - alpha) * r * af / degc
     mask = jnp.isfinite(w).astype(r.dtype)
-    spread = push @ mask
+    spread = jnp.dot(push, mask, precision=jax.lax.Precision.HIGHEST)
     r_out = r * (1.0 - af) + spread
     return p_out, r_out, acc + push

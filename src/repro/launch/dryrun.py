@@ -32,7 +32,6 @@ from repro.configs import base as cfg_base
 from repro.configs.shapes import SHAPES, applicable, skip_reason
 from repro.launch import hlo as hlo_lib
 from repro.launch import probes as probes_lib
-from repro.launch import mesh as mesh_lib
 from repro.launch.mesh import chips, make_production_mesh
 from repro.launch.steps import build_setup, rules_for
 
@@ -58,7 +57,7 @@ def run_probes(cfg, shape, rules, mesh):
     coll_by_kind = {}
     breakdown = []
     for p in probes:
-        with mesh_lib.set_mesh(rules.mesh), probes_lib.probe_tracing():
+        with jax.set_mesh(rules.mesh), probes_lib.probe_tracing():
             compiled = jax.jit(p.fn, in_shardings=p.in_shardings).lower(
                 *p.arg_specs).compile()
         cs = hlo_lib.cost_summary(compiled)
@@ -110,7 +109,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     try:
         t0 = time.time()
         fn, arg_specs, in_sh, donate = build_setup(cfg, shape, mesh, rules)
-        with mesh_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=in_sh,
                               donate_argnums=donate).lower(*arg_specs)
             t_lower = time.time() - t0

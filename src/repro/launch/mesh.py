@@ -1,44 +1,28 @@
-"""Production meshes (+ the jax-version compat shims every caller shares).
+"""Production meshes.
 
 A function, not a module-level constant: importing this module never
 touches jax device state (contract requirement — device count is locked at
 first jax init, and only launch/dryrun.py sets the 512-device flag).
 
-Compat: jax >= 0.5/0.6 grew ``jax.sharding.AxisType`` / the ``axis_types=``
-kwarg and ``jax.set_mesh``; on 0.4.x the equivalents are the default
-(auto) axis behaviour and the ``with mesh:`` resource-env context.
-``compat_make_mesh`` / ``set_mesh`` paper over the difference so drivers
-and test scripts run on both.
+Every mesh here uses ``AxisType.Auto`` axes: the model code shards by
+logical-axis rules and lets the partitioner propagate, so none of its
+axes are explicit.
 """
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}  # noqa: E731
-except ImportError:                     # jax 0.4.x: Auto is the only mode
-    AxisType = None
-    _AXIS_KW = lambda n: {}             # noqa: E731
+from jax.sharding import AxisType
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the kwarg exists."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_AXIS_KW(len(shape)))
-
-
-def set_mesh(mesh):
-    """Context manager making ``mesh`` ambient for bare-PartitionSpec use:
-    ``jax.set_mesh`` on new jax, the mesh resource-env context on 0.4.x."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh                         # Mesh is itself a context manager
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -47,7 +31,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     if data * model > n:
         data, model = 1, 1
-    return compat_make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def chips(mesh) -> int:

@@ -111,6 +111,8 @@ def serve_graph(args):
 
 def main():
     from repro.graphs.generators import SUITES   # jax-free import
+    from repro.launch.persistent_cache import enable_persistent_cache
+    enable_persistent_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", choices=("lm", "graph"), default="lm")

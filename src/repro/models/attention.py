@@ -215,7 +215,6 @@ def decode_attend_partitioned(q, k, v, length, mesh, *, window=None,
     q: [B,H,hd] (replicated over seq_axis); k,v: [B,S,Hkv,hd] with S sharded
     over ``seq_axis`` and B over ``batch_axes``; length: [B].
     """
-    from repro.models.sharding import shard_map_compat
 
     S = k.shape[1]
     batch_axes = tuple(a for a in batch_axes if a in mesh.axis_names)
@@ -229,11 +228,11 @@ def decode_attend_partitioned(q, k, v, length, mesh, *, window=None,
         m, l, acc = _decode_partial(q, k, v, kv_pos, length, window)
         return combine_partials(m, l, acc, seq_axis).astype(q.dtype)
 
-    return shard_map_compat(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, None, None), P(bspec, seq_axis, None, None),
                   P(bspec, seq_axis, None, None), P(bspec)),
-        out_specs=P(bspec, None, None))(q, k, v, length)
+        out_specs=P(bspec, None, None), check_vma=False)(q, k, v, length)
 
 
 # ---------------------------------------------------------------------------

@@ -111,7 +111,6 @@ def embed(p, tokens, cdtype, rules=None):
 def _sharded_embed(table, tokens, rules, ax, cdtype):
     from jax.sharding import PartitionSpec as P
 
-    from repro.models.sharding import shard_map_compat
     mesh = rules.mesh
     n = rules._mesh_size(ax)
     v_loc = table.shape[0] // n
@@ -127,9 +126,10 @@ def _sharded_embed(table, tokens, rules, ax, cdtype):
         x = x * ok[..., None].astype(cdtype)
         return jax.lax.psum(x, ax)
 
-    return shard_map_compat(local, mesh=mesh,
-                            in_specs=(P(ax, None), P(bspec, None)),
-                            out_specs=P(bspec, None, None))(table, tokens)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(ax, None), P(bspec, None)),
+                         out_specs=P(bspec, None, None),
+                         check_vma=False)(table, tokens)
 
 
 def unembed(p, x, true_vocab=None):

@@ -27,7 +27,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models import attention as attn_lib
 from repro.models.layers import _act, apply_rope
-from repro.models.sharding import shard_map_compat
 
 
 def _tp(rules):
@@ -74,14 +73,14 @@ def manual_mlp(lp, x, cfg, rules):
         # pass crashes on bf16 all-reduce (hard abort)
         return jax.lax.psum(y.astype(jnp.float32), "model").astype(x.dtype)
 
-    return shard_map_compat(
+    return jax.shard_map(
         local, mesh=mesh,
         # auto axes ("data"/"pod") may not appear in specs: the batch dim's
         # FSDP/DP sharding passes through shard_map untouched
         in_specs=(P(None, "model"), P("model", None), P(None, "model"),
                   P(None, None, None)),
         out_specs=P(None, None, None),
-        axis_names={"model"})(
+        axis_names={"model"}, check_vma=False)(
             lp["wi"], lp["wo"], lp.get("wg", lp["wi"]),
             x.astype(jnp.float32))
 
@@ -145,7 +144,7 @@ def manual_attention(lp, x, positions, cfg, rules, *, window=None,
         kvspec, kvb = P(None, None, "model"), P(None, None)
     else:
         kvspec, kvb = P(None, None, None), P(None, None)
-    return shard_map_compat(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, "model", None), kvspec, kvspec,
                   P("model", None, None),
@@ -154,7 +153,7 @@ def manual_attention(lp, x, positions, cfg, rules, *, window=None,
                   kvb if has_bias else P(None),
                   P(None, None, None)),
         out_specs=P(None, None, None),
-        axis_names={"model"})(
+        axis_names={"model"}, check_vma=False)(
             lp["wq"], lp["wk"], lp["wv"], lp["wo"],
             lp.get("bq", zeros), lp.get("bk", zeros), lp.get("bv", zeros),
             x.astype(jnp.float32))

@@ -30,27 +30,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """jax.shard_map across jax versions.
-
-    New jax: ``axis_names`` marks the manual axes (others stay auto) and
-    vma checking is off.  jax 0.4.x: translate to the experimental API's
-    ``auto=`` complement-set and ``check_rep=False``.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": False}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    # 0.4.x's partial-manual (auto=) partitioner miscompiles this pattern
-    # (manual-subgroup check failure), so run full-manual there: axes absent
-    # from a spec are treated as replicated — correct, if less sharded.
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 class AxisRules:
     """Mapping logical axis name -> mesh axis (str | tuple | None)."""
 

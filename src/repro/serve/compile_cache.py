@@ -41,7 +41,7 @@ import collections
 import itertools
 import threading
 import time
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -223,13 +223,21 @@ class MegastepCache:
                 ev.set()
 
     def warm_async(self, session, graph: str, kind: str, capacity: int,
+                   on_error: Callable[[Exception], None],
                    **params) -> threading.Thread:
         """Fire-and-forget prewarm; returns the (daemon) thread for tests
-        that want to join it."""
+        that want to join it.  A failed compile is never dropped: it is
+        handed to ``on_error`` (the server records it and re-raises it to
+        its callers)."""
+        def warm():
+            try:
+                self.get_or_build(session, graph, kind, capacity, **params)
+            except Exception as exc:  # noqa: BLE001 — handed on
+                on_error(exc)
+
         t = threading.Thread(
-            target=self.get_or_build,
-            args=(session, graph, kind, capacity), kwargs=params,
-            name=f"warm-{graph}-{kind}-{capacity}", daemon=True)
+            target=warm, name=f"warm-{graph}-{kind}-{capacity}",
+            daemon=True)
         t.start()
         return t
 

@@ -22,6 +22,11 @@ flight never blocks submissions — a submit racing its own pool's chunk
 simply parks on the executor lock and lands at the next chunk boundary,
 the only point where lane mutation was ever legal (§3.3 exactness).
 
+A lane never dies silently: an exception in a worker (a compile refused
+by the device, an HBM allocation failure) is recorded on the server, the
+lanes halt, and ``result()``/``serve()``/``shutdown()`` re-raise it in
+the caller's thread instead of letting it wait out a timeout.
+
 Pool workers replace the synchronous path's explicit pool arbitration:
 each pool pumps on its own thread and the OS scheduler interleaves them,
 while request priorities still shape *admission order* within a pool.
@@ -52,6 +57,12 @@ class PoolWorker(threading.Thread):
         self.pool = pool
 
     def run(self):
+        try:
+            self._pump()
+        except Exception as exc:  # noqa: BLE001 — handed to callers
+            self.server._fail(exc)
+
+    def _pump(self):
         srv, pool = self.server, self.pool
         while True:
             with srv._lock:
@@ -113,6 +124,12 @@ class DeliveryWorker(threading.Thread):
         self.q.put(None)
 
     def run(self):
+        try:
+            self._drain()
+        except Exception as exc:  # noqa: BLE001 — handed to callers
+            self.server._fail(exc)
+
+    def _drain(self):
         srv = self.server
         while True:
             item = self.q.get()

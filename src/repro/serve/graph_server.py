@@ -233,7 +233,8 @@ def default_autoscaler(pool_stats: dict) -> int:
         mem=pool_stats["mem"], n_vertices=pool_stats["n_vertices"],
         block_size=pool_stats["block_size"],
         min_capacity=pool_stats["min_capacity"],
-        max_capacity=pool_stats["max_capacity"])
+        max_capacity=pool_stats["max_capacity"],
+        adjacency_bytes=pool_stats["adjacency_bytes"])
 
 
 class GraphServer:
@@ -344,6 +345,9 @@ class GraphServer:
         # follower rids (fan-out happens at delivery)
         self._dedup: Dict[tuple, int] = {}
         self._followers: Dict[int, List[int]] = {}
+        # the first exception of a background lane (pump, delivery, warm
+        # compile); re-raised by result/serve/shutdown
+        self._failure: Optional[Exception] = None
 
     # ---------------------------------------------------------- registration
 
@@ -378,6 +382,7 @@ class GraphServer:
                                                      self.capacity))
         for kind in kinds:
             self.cache.warm_async(session, name, kind, cap0,
+                                  on_error=self._fail,
                                   **self._warm_params(session, kind))
         return self
 
@@ -453,6 +458,7 @@ class GraphServer:
                                                 self.capacity))
             for kind in kinds:
                 self.cache.warm_async(session, name, kind, cap0,
+                                      on_error=self._fail,
                                       **self._warm_params(session, kind))
         return self
 
@@ -827,6 +833,7 @@ class GraphServer:
             "capacity": pool.capacity, "mem": plan.mem,
             "n_vertices": pool.session.graph.n,
             "block_size": pool.exec.bg.block_size,
+            "adjacency_bytes": pool.exec.bg.blocks.nbytes,
             "min_capacity": 1, "max_capacity": self.max_capacity,
         }))
         if hint < 1:
@@ -880,6 +887,18 @@ class GraphServer:
             p.cv.notify_all()
         self._resp_cv.notify_all()
 
+    def _fail(self, exc: Exception):
+        """Record a background lane's exception (the first one wins) and
+        halt the lanes, waking every blocked caller to re-raise it."""
+        with self._lock:
+            if self._failure is None:
+                self._failure = exc
+            self._halt_locked()
+
+    def _raise_failure(self):
+        if self._failure is not None:
+            raise self._failure
+
     def shutdown(self) -> Dict[int, GraphResponse]:
         """Stop the lanes at their next chunk boundary and join them.
         Unserved requests stay booked — ``start()`` again to resume —
@@ -893,6 +912,7 @@ class GraphServer:
         if delivery is not None:
             delivery.stop()
             delivery.join()
+        self._raise_failure()
         return self.responses
 
     def wait_drained(self, timeout: Optional[float] = None) -> bool:
@@ -910,13 +930,15 @@ class GraphServer:
         Requires running lanes (``start``/``serve_forever``) unless the
         response already exists; raises ``KeyError`` for unknown rids,
         ``TimeoutError`` on timeout, ``RuntimeError`` if the server halts
-        first."""
+        first, and re-raises a background lane's exception as soon as it
+        happens."""
         with self._lock:
             resp = self.responses.get(rid)
             if resp is not None:
                 return resp
             if rid not in self._tickets:
                 raise KeyError(f"unknown request id {rid}")
+            self._raise_failure()
             if not self._running:
                 raise RuntimeError(
                     f"request {rid} has no response and the serving lanes "
@@ -925,6 +947,7 @@ class GraphServer:
                 lambda: rid in self.responses or not self._running, timeout)
             resp = self.responses.get(rid)
             if resp is None:
+                self._raise_failure()
                 if self._running:
                     raise TimeoutError(
                         f"request {rid} not served within {timeout}s")
@@ -991,10 +1014,12 @@ class GraphServer:
         response (or the round budget runs out); returns the response
         table."""
         start = self.rounds
+        self._raise_failure()
         while self.pending and (max_rounds is None
                                 or self.rounds - start < max_rounds):
             if not self.step():
                 break
+            self._raise_failure()
         return self.responses
 
     def serve_forever(self, arrivals: Optional[

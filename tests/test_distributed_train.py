@@ -14,9 +14,10 @@ _SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.distributed import _shard_map as shard_map
-    from repro.launch.mesh import compat_make_mesh, set_mesh
+    from jax.sharding import AxisType
 
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     # ---- 1. compressed_psum == f32 psum within quantization tolerance ----
     from repro.train.compress import compressed_psum
@@ -71,7 +72,7 @@ _SCRIPT = textwrap.dedent("""
     sharded = jax.jit(make_train_step(model, opt, constant(1e-3),
                                       rules=rules, microbatches=2),
                       in_shardings=(st_sh, b_sh))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         s2, m2 = sharded(state, batch)
     # microbatched grad averaging reorders float sums: tolerance not exact
     d = max(float(jnp.max(jnp.abs(a.astype(jnp.float32)
@@ -91,7 +92,7 @@ _SCRIPT = textwrap.dedent("""
     v = jnp.asarray(rng.normal(size=(B, S, Hkv, hd)), jnp.float32)
     length = jnp.asarray([5, 17, 32, 9], jnp.int32)
     want = A.decode_attend_local(q, k, v, jnp.arange(S), length)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = A.decode_attend_partitioned(q, k, v, length, mesh,
                                           batch_axes=("data",))
     err = float(jnp.max(jnp.abs(got - want)))

@@ -13,7 +13,7 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import compat_make_mesh, set_mesh
+    from jax.sharding import AxisType
     from repro.configs.base import get_config
     from repro.configs.shapes import ShapeConfig
     from repro.models.factory import build_model
@@ -34,7 +34,7 @@ _SCRIPT = textwrap.dedent("""
     def run_steps(state, mesh, rules, n, start):
         ts = make_train_step(model, opt, constant(1e-3), rules=rules)
         if mesh is not None:
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 ts = jax.jit(ts)
                 for s in range(start, start + n):
                     state, m = ts(state, data(s))
@@ -50,8 +50,10 @@ _SCRIPT = textwrap.dedent("""
         6, 0)
 
     # elastic: 3 steps on mesh (2,4), checkpoint, resume 3 on mesh (4,2)
-    meshA = compat_make_mesh((2, 4), ("data", "model"))
-    meshB = compat_make_mesh((4, 2), ("data", "model"))
+    meshA = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
+    meshB = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     rulesA = rules_for(cfg, meshA)
     stA, _ = run_steps(init_train_state(model, jax.random.PRNGKey(0), opt),
                        meshA, rulesA, 3, 0)

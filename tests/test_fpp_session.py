@@ -41,6 +41,42 @@ def test_planner_keeps_enough_partitions():
     assert -(-g.n // b) >= 2             # never collapses to one partition
 
 
+class _Device:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_memory_model_is_keyed_by_device_kind():
+    """A TPU gets its table row (the VMEM limit the kernels request, the
+    chip's HBM); an unknown TPU kind raises instead of guessing; other
+    backends keep the explicit defaults."""
+    from repro.kernels.tpu import TPU_MEMORY
+    v5e = MemoryModel.for_device(_Device("tpu", "TPU v5 lite"))
+    row = TPU_MEMORY["TPU v5 lite"]
+    assert (v5e.vmem_bytes, v5e.hbm_bytes) == (row.vmem_limit_bytes,
+                                               row.hbm_bytes)
+    with pytest.raises(ValueError, match="TPU v99"):
+        MemoryModel.for_device(_Device("tpu", "TPU v99"))
+    assert MemoryModel.for_device(_Device("cpu", "cpu")) == MemoryModel()
+
+
+def test_road_graph_plan_fits_v5e():
+    """The chip smoke graph (grid2d 512x512, Q=64) on v5e: the plan's B
+    keeps the fused visit inside the kernels' VMEM limit and the state
+    plus the dense blocks inside HBM — 1024, where 2048's fused visit
+    would not fit VMEM."""
+    from repro.fpp.planner import FUSED_DMAX_BUDGET, est_dmax, make_plan
+    g = grid2d(512, 512, seed=0)
+    mem = MemoryModel.for_device(_Device("tpu", "TPU v5 lite"))
+    b = make_plan(g, 64, mem=mem).block_size
+    assert b == 1024
+    assert mem.fused_working_set(b, 64, 2, FUSED_DMAX_BUDGET) \
+        <= mem.vmem_bytes
+    assert not mem.fits(2 * b, 64, g.n, fused=True)
+    blocks = -(-g.n // b) * (1 + est_dmax(g, b)) * b * b * 4
+    assert mem.state_bytes(g.n, 64, b) + blocks <= mem.hbm_bytes
+
+
 def test_plan_tune_measures_and_picks_feasible():
     g = grid2d(16, 16, seed=2)
     srcs = np.array([0, 100, 200, 255])

@@ -252,7 +252,7 @@ def test_f64_promotion_caught():
     def fn(x):
         return x.astype(jnp.float64) * 2.0
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         findings = check_program(
             _program(fn, (jax.ShapeDtypeStruct((8,), jnp.float32),)))
     assert "x64-promotion" in _codes(findings)

@@ -315,6 +315,10 @@ def test_autoscale_capacity_hint_is_memory_clamped():
     got = autoscale_capacity(100, 0, mem=tiny, n_vertices=1024,
                              block_size=64)
     assert got <= 8 and tiny.fits(64, got, 1024)
+    # the graph's staged blocks share HBM with the state planes
+    blocks = mem.hbm_bytes - mem.state_bytes(1024, 2, 64)
+    assert autoscale_capacity(100, 0, max_capacity=16,
+                              adjacency_bytes=blocks, **kw) == 2
 
 
 def test_server_grows_pool_capacity_under_backlog():
@@ -368,6 +372,100 @@ def test_concurrent_submitters_bit_identical_and_result_blocks():
         with pytest.raises(KeyError):
             server.result(10_000, timeout=1)
     finally:
+        server.shutdown()
+
+
+def test_failed_pump_is_raised_by_result_not_timed_out(monkeypatch):
+    """A pump that raises on the device (a refused compile, an HBM OOM)
+    must reach the caller: ``result()`` re-raises the very exception
+    within the call instead of waiting out its timeout, and
+    ``shutdown()`` raises it again after joining the lanes."""
+    import time
+
+    from repro.fpp.streaming import StreamingExecutor
+    err = RuntimeError("RESOURCE_EXHAUSTED: device refused the chunk")
+
+    def boom(self, max_visits):
+        raise err
+
+    monkeypatch.setattr(StreamingExecutor, "pump", boom)
+    g = grid2d(6, 6, seed=1)
+    server = GraphServer(capacity=1, k_visits=8, autoscaler=None)
+    server.register_graph("g", g, num_queries=1, block_size=16)
+    server.start()
+    rid = server.submit(GraphRequest(kind="sssp", source=0, graph="g"))
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError) as got:
+        server.result(rid, timeout=60)
+    assert got.value is err
+    assert time.monotonic() - t0 < 30
+    with pytest.raises(RuntimeError) as again:
+        server.shutdown()
+    assert again.value is err
+
+
+def test_concurrent_pump_failures_record_one_exception(monkeypatch):
+    """Eight pools fail on eight threads at once (more lanes than cores,
+    a shortened switch interval): exactly one exception is recorded, and
+    every blocked ``result`` re-raises that same one."""
+    import sys
+
+    from repro.fpp.streaming import StreamingExecutor
+    errors = []
+
+    def boom(self, max_visits):
+        err = RuntimeError(f"pump {id(self)}")
+        errors.append(err)
+        raise err
+
+    monkeypatch.setattr(StreamingExecutor, "pump", boom)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        server = GraphServer(capacity=1, k_visits=8, autoscaler=None)
+        for i in range(8):
+            server.register_graph(f"g{i}", grid2d(4, 4, seed=i),
+                                  num_queries=1, block_size=8)
+        server.start()
+        rids = [server.submit(GraphRequest(kind="sssp", source=0,
+                                           graph=f"g{i}"))
+                for i in range(8)]
+        raised = set()
+        for rid in rids:
+            with pytest.raises(RuntimeError) as got:
+                server.result(rid, timeout=60)
+            raised.add(id(got.value))
+        with pytest.raises(RuntimeError):
+            server.shutdown()
+    finally:
+        sys.setswitchinterval(old)
+    assert len(raised) == 1 and errors
+    assert id(server._failure) in raised
+
+
+def test_failed_prewarm_compile_is_raised_by_result(monkeypatch):
+    """A background warm compile that fails is handed to the server, whose
+    callers see it instead of a timeout."""
+    import repro.serve.compile_cache as cc
+    err = ValueError("compile refused")
+
+    def refuse(*a, **k):
+        raise err
+
+    monkeypatch.setattr(cc, "build_warm_megastep", refuse)
+    g = grid2d(6, 6, seed=1)
+    server = GraphServer(capacity=1, k_visits=8, prewarm=("sssp",))
+    server.register_graph("g", g, num_queries=1, block_size=16)
+    for t in list(threading.enumerate()):
+        if t.name.startswith("warm-"):
+            t.join(timeout=60)
+    assert server._failure is err
+    server.start()
+    rid = server.submit(GraphRequest(kind="sssp", source=0, graph="g"))
+    with pytest.raises(ValueError) as got:
+        server.result(rid, timeout=60)
+    assert got.value is err
+    with pytest.raises(ValueError):
         server.shutdown()
 
 

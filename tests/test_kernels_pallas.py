@@ -73,14 +73,27 @@ def test_ppr_push_sweep(Q, B, alpha, eps):
                                    atol=1e-6)
 
 
+def _relax_call(d, w, **kw):
+    """``minplus_relax`` in a one-program kernel of its own, as the fused
+    visit calls it (chunk size and ``skip_inactive`` are its options)."""
+    from jax.experimental import pallas as pl
+    from repro.kernels.minplus.minplus import minplus_relax
+
+    def kernel(d_ref, w_ref, o_ref):
+        o_ref[...] = minplus_relax(d_ref, w_ref, **kw)
+    return pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(
+        d.shape, d.dtype), interpret=True)(d, w)
+
+
 @pytest.mark.parametrize("Q,B", [(3, 16), (8, 32), (64, 64)])
 @pytest.mark.parametrize("u_chunk", [4, 8, 16])
 def test_minplus_tile_skip_inactive_bitwise(Q, B, u_chunk):
     """The fused visit's in-kernel relax: chunked, chunk-skipping, and
-    single-chunk paths of ``minplus_tile`` all agree with the ref down to
+    single-chunk paths of ``minplus_relax`` all agree with the ref down to
     the bit — skipping an all-+inf source chunk contributes only +inf
-    candidates, and chunking only reassociates an exact min."""
-    from repro.kernels.minplus.minplus import minplus_tile
+    candidates, and chunking only reassociates an exact min.  The minplus
+    kernel itself (dense relax) is held to the same bits."""
+    from repro.kernels.minplus.minplus import minplus_pallas_call
     from repro.kernels.minplus.ref import minplus_ref
     d = jnp.asarray(np.where(RNG.random((Q, B)) < 0.7, np.inf,
                              RNG.random((Q, B)) * 9), jnp.float32)
@@ -90,9 +103,12 @@ def test_minplus_tile_skip_inactive_bitwise(Q, B, u_chunk):
     for kw in ({"u_chunk": u_chunk}, {"u_chunk": u_chunk,
                                       "skip_inactive": True},
                {"u_chunk": B}, {"u_chunk": B, "skip_inactive": True}):
-        got = np.nan_to_num(np.asarray(minplus_tile(d, w, **kw)),
+        got = np.nan_to_num(np.asarray(_relax_call(d, w, **kw)),
                             posinf=1e30)
         np.testing.assert_array_equal(got, want, err_msg=str(kw))
+    got = minplus_pallas_call(d, w, u_chunk=u_chunk, interpret=True)
+    np.testing.assert_array_equal(
+        np.nan_to_num(np.asarray(got), posinf=1e30), want)
 
 
 @pytest.mark.parametrize("Q,B", [(3, 16), (64, 64)])

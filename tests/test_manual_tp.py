@@ -14,13 +14,14 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import compat_make_mesh, set_mesh
+    from jax.sharding import AxisType
     from repro.configs.base import get_config
     from repro.models.factory import build_model
     from repro.launch.steps import rules_for
     from repro.models import manual_tp
 
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     for arch in ("qwen2-72b", "stablelm-12b"):
         cfg = get_config(arch).reduced()
@@ -38,7 +39,7 @@ _SCRIPT = textwrap.dedent("""
         base, _ = model.logits(params, batch, remat=False)   # no rules
 
         rules.rules["manual_tp"] = True
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got, _ = jax.jit(lambda p, b: model.logits(
                 p, b, rules=rules, remat=False))(params, batch)
         err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
