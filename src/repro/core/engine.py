@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import visit as _visit
 from repro.core.graph import BlockGraph
 from repro.core.oracles import decode_kreach
@@ -59,10 +60,12 @@ MODES = ("minplus", "push", "cc", "kreach")
 class VisitStats(NamedTuple):
     visits: int
     rounds: int
-    blocks_loaded: int
     modeled_bytes: float  # modeled HBM->VMEM traffic (cache-miss analogue)
     host_syncs: int = 0   # device->host round trips the run paid (megastep:
     #                       one per K-visit chunk; host loop: one per visit)
+    visit_counts: Optional[np.ndarray] = None  # [P] int64 visits/partition
+    megastep_traces: int = 0  # times this run traced its megastep
+    chunk_reads: int = 0      # device->host reads at chunk (visit) bounds
 
 
 # ---------------------------------------------------------------------------
@@ -229,7 +232,6 @@ class FPPEngine:
         out_blocks = (bg.nbr_blk >= 0).sum(axis=1)
         self._visit_bytes = ((1 + out_blocks) * B * B * 4
                              + 2 * num_queries * B * 4).astype(np.float64)
-        self._visit_blocks = (1 + out_blocks).astype(np.int64)
 
     def init_state(self, sources: np.ndarray) -> VisitState:
         if self.mode == "cc":
@@ -261,11 +263,14 @@ class FPPEngine:
                 f"got {len(sources)} sources for an engine planned for "
                 f"num_queries={self.num_queries}; rebuild the engine (or the "
                 f"session plan) with num_queries={len(sources)}")
-        state = self.init_state(np.asarray(sources))
+        with spans.span(spans.INIT_STATE) as sp:
+            state = self.init_state(np.asarray(sources))
+            spans.note(sp, bytes=sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(state)))
         max_visits = max_visits or 2000 * self.bg.num_parts
         if host_loop:
             return self._run_host_loop(state, max_visits, record_order)
-        visits = rounds = syncs = 0
+        visits = rounds = syncs = reads = traces = 0
         order: list = []
         counts = np.zeros(self.dg.num_parts, dtype=np.int64)
         # edge counts leave the device as an exact (hi, lo) int32 pair per
@@ -273,21 +278,35 @@ class FPPEngine:
         # 2^24 (f32) edges.
         edges = np.zeros(self.num_queries, dtype=np.float64)
         key = jax.random.PRNGKey(self.seed)
+        # each read below is one device->host transfer
+        harvest_reads = 5 if record_order else 4
         while visits < max_visits:
             limit = min(self.k_visits, max_visits - visits)
-            state, ms = self._megastep(state, jnp.int32(visits),
-                                       jnp.int32(limit), key)
+            chunk = syncs
+            with spans.span(spans.DISPATCH, chunk=chunk) as sp:
+                # a wrapped megastep may not count its traces
+                before = getattr(self._megastep, "traces", 0)
+                state, ms = self._megastep(state, jnp.int32(visits),
+                                           jnp.int32(limit), key)
+                traced = getattr(self._megastep, "traces", 0) - before
+                spans.note(sp, traces=traced)
+            traces += traced
             syncs += 1
-            v = int(ms.visits)          # the one host sync per chunk
+            with spans.span(spans.SYNC, chunk=chunk) as sp:
+                v = int(ms.visits)      # the one host sync per chunk
+                spans.note(sp, visits=v)
+            reads += 1
             if v == 0:
                 break
             key = ms.key
-            edges += _visit.harvest_edges(ms.eq_hi, ms.eq_lo)
-            counts += np.asarray(ms.visit_counts, dtype=np.int64)
+            with spans.span(spans.HARVEST, chunk=chunk, reads=harvest_reads):
+                edges += _visit.harvest_edges(ms.eq_hi, ms.eq_lo)
+                counts += np.asarray(ms.visit_counts, dtype=np.int64)
+                rounds += int(ms.rounds)
+                if record_order:
+                    order.extend(int(x) for x in np.asarray(ms.order)[:v])
+            reads += harvest_reads
             visits += v
-            rounds += int(ms.rounds)
-            if record_order:
-                order.extend(int(x) for x in np.asarray(ms.order)[:v])
             if v < limit:
                 # the while-cond can only exit below the limit when no
                 # partition holds a pending op: the run is complete, no
@@ -295,17 +314,18 @@ class FPPEngine:
                 break
         stats = VisitStats(
             visits=visits, rounds=rounds,
-            blocks_loaded=int(counts @ self._visit_blocks),
             modeled_bytes=float(counts @ self._visit_bytes),
-            host_syncs=syncs)
-        return self._finalize(state, edges, stats, order)
+            host_syncs=syncs, visit_counts=counts, megastep_traces=traces,
+            chunk_reads=reads)
+        with spans.span(spans.FINALIZE):
+            return self._finalize(state, edges, stats, order)
 
     def _run_host_loop(self, state: VisitState, max_visits: int,
                        record_order: bool) -> EngineResult:
         """Legacy per-visit loop: prio/stamp/ops sync to host, numpy argmin,
         one jitted visit per dispatch — O(visits) host synchronizations."""
-        visits = rounds = blocks = 0
-        traffic = 0.0
+        visits = rounds = reads = 0
+        counts = np.zeros(self.dg.num_parts, dtype=np.int64)
         order: list = []
         counter = 0
         edges = np.zeros(self.num_queries, dtype=np.float64)
@@ -313,6 +333,7 @@ class FPPEngine:
             prio = np.asarray(state.prio)
             stamp = np.asarray(state.stamp)
             ops = np.asarray(state.ops_count)
+            reads += 3
             p = self.scheduler.select(prio, stamp, ops)
             if p is None:
                 break
@@ -322,13 +343,16 @@ class FPPEngine:
             counter += 1
             visits += 1
             rounds += int(r)
-            blocks += int(self._visit_blocks[p])
-            traffic += float(self._visit_bytes[p])
+            reads += 2
+            counts[p] += 1
             if record_order:
                 order.append(p)
-        stats = VisitStats(visits=visits, rounds=rounds, blocks_loaded=blocks,
-                           modeled_bytes=traffic, host_syncs=visits)
-        return self._finalize(state, edges, stats, order)
+        stats = VisitStats(visits=visits, rounds=rounds,
+                           modeled_bytes=float(counts @ self._visit_bytes),
+                           host_syncs=visits, visit_counts=counts,
+                           chunk_reads=reads)
+        with spans.span(spans.FINALIZE):
+            return self._finalize(state, edges, stats, order)
 
     def _finalize(self, state: VisitState, edges: np.ndarray,
                   stats: VisitStats, order: list) -> EngineResult:

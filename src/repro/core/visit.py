@@ -322,12 +322,15 @@ def _make_visit_body(dg, algebra: VisitAlgebra, max_rounds: int) -> Callable:
         return jax.lax.dynamic_index_in_dim(dg.blocks, k, keepdims=False)
 
     def visit(state: VisitState, p: jax.Array, counter: jax.Array):
+        # the scopes name the visit's phases in the compiled program's op
+        # metadata (``visit/relax`` ...), so a device trace reads by phase
         kd = dg.diag_blk[p]
         w_pp, nnz_pp, deg_p = block(kd), dg.row_nnz[kd], dg.deg[p]
-        planes_row = tuple(x[p] for x in state.planes)
-        buf_row = state.buf[p]
-        carry0 = algebra.begin(planes_row, buf_row, deg_p)
         budget = dg.edge_budget[p]
+        with jax.named_scope("visit/apply"):
+            planes_row = tuple(x[p] for x in state.planes)
+            buf_row = state.buf[p]
+            carry0 = algebra.begin(planes_row, buf_row, deg_p)
 
         def cond(c):
             carry, eq, rounds = c
@@ -343,9 +346,16 @@ def _make_visit_body(dg, algebra: VisitAlgebra, max_rounds: int) -> Callable:
             return algebra.step(carry, act, w_pp, deg_p), eq, rounds + 1
 
         eq0 = jnp.zeros(buf_row.shape[0], dtype=jnp.int32)
-        carry, eq, rounds = jax.lax.while_loop(
-            cond, body, (carry0, eq0, jnp.int32(0)))
+        with jax.named_scope("visit/relax"):
+            carry, eq, rounds = jax.lax.while_loop(
+                cond, body, (carry0, eq0, jnp.int32(0)))
+        with jax.named_scope("visit/emit"):
+            state, eq = emit(state, p, counter, carry, eq)
+        with jax.named_scope("visit/writeback"):
+            state = writeback(state, p, counter, carry, deg_p)
+        return state, (rounds, eq)
 
+    def emit(state: VisitState, p, counter, carry, eq):
         # ---- emission to neighbor partitions (Alg. 2 line 16): ONE batched
         # contrib over all neighbor blocks (vmap) + a single segment-combine
         # scatter, instead of a serial dmax-step fori_loop ----
@@ -375,18 +385,22 @@ def _make_visit_body(dg, algebra: VisitAlgebra, max_rounds: int) -> Callable:
         stamp = state.stamp.at[jj].set(
             jnp.where(was_empty[j0] & jnp.isfinite(newprio), counter,
                       state.stamp[j0]), mode="drop")
+        return state._replace(buf=buf, prio=prio, ops_count=ops_count,
+                              stamp=stamp), eq
 
+    def writeback(state: VisitState, p, counter, carry, deg_p):
         # ---- write back own planes, keep yielded ops, refresh priority ----
         new_rows, keep_row = algebra.finish(carry, deg_p)
-        buf = buf.at[p].set(keep_row)
+        buf = state.buf.at[p].set(keep_row)
         own_prio, own_ops = algebra.prio_of(keep_row, new_rows, deg_p)
-        prio = prio.at[p].set(own_prio)
-        ops_count = ops_count.at[p].set(own_ops)
-        stamp = stamp.at[p].set(jnp.where(jnp.isfinite(own_prio), counter,
-                                          jnp.int32(_BIG_STAMP)))
+        prio = state.prio.at[p].set(own_prio)
+        ops_count = state.ops_count.at[p].set(own_ops)
+        stamp = state.stamp.at[p].set(jnp.where(jnp.isfinite(own_prio),
+                                                counter,
+                                                jnp.int32(_BIG_STAMP)))
         planes = tuple(x.at[p].set(nr)
                        for x, nr in zip(state.planes, new_rows))
-        return VisitState(planes, buf, prio, ops_count, stamp), (rounds, eq)
+        return VisitState(planes, buf, prio, ops_count, stamp)
 
     return visit
 
@@ -400,11 +414,15 @@ class GraphBound:
     ``lower(...).compile()`` all take the remaining arguments only, so a
     bound program and its AOT-compiled executable share one calling
     convention.
+
+    ``traces`` counts how often the program's Python body ran, that is,
+    was traced (a program that counts its traces bumps it from its body).
     """
 
     def __init__(self, fn, graph):
         self.fn = fn
         self.graph = graph
+        self.traces = 0
 
     def __call__(self, *args):
         return self.fn(self.graph, *args)
@@ -575,6 +593,7 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
     @jax.jit
     def megastep(g, state: VisitState, counter: jax.Array,
                  limit: jax.Array, key: jax.Array):
+        bound.traces += 1               # trace time only: counts retraces
         visit = visit_of(g)
         limit_k = jnp.minimum(jnp.int32(limit), jnp.int32(K))
 
@@ -589,8 +608,9 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
                 key, sub = jax.random.split(key)  # policy consumes entropy
             else:
                 sub = key
-            prio, stamp, ops_count = meta(st)
-            p = device_select(policy, prio, stamp, ops_count, sub)
+            with jax.named_scope("select"):
+                prio, stamp, ops_count = meta(st)
+                p = device_select(policy, prio, stamp, ops_count, sub)
             st, (r, eq) = visit(st, p, counter + k)
             lo = lo + eq
             spill = lo >> EDGE_SHIFT
@@ -607,14 +627,16 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
         st, k, rounds, hi, lo, counts, order, key = jax.lax.while_loop(
             cond, body, init)
         st = leave(st)
-        lane_pending = (jnp.any(
-            algebra.pending(st.buf[:P], st.planes, g.deg), axis=(0, 2))
-            if harvest_mask else jnp.zeros((0,), dtype=bool))
+        with jax.named_scope("harvest_mask"):
+            lane_pending = (jnp.any(
+                algebra.pending(st.buf[:P], st.planes, g.deg), axis=(0, 2))
+                if harvest_mask else jnp.zeros((0,), dtype=bool))
         return st, MegastepStats(visits=k, rounds=rounds, eq_hi=hi, eq_lo=lo,
                                  visit_counts=counts, order=order,
                                  lane_pending=lane_pending, key=key)
 
-    return GraphBound(megastep, dg)
+    bound = GraphBound(megastep, dg)
+    return bound
 
 
 def harvest_edges(eq_hi: np.ndarray, eq_lo: np.ndarray) -> np.ndarray:

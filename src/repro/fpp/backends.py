@@ -22,6 +22,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import spans
 from repro.core.baselines import (global_minplus, global_push,
                                   global_random_walks)
 from repro.core.engine import FPPEngine
@@ -148,19 +149,22 @@ def run_query(backend: str, kind: str, bg: BlockGraph, sources: np.ndarray,
         return _rw_result(res, {"supersteps": res.visits})
 
     if backend == "engine":
-        eng = FPPEngine(bg, mode=_ENGINE_MODE[kind],
-                        num_queries=len(sources),
-                        yield_config=yield_config or YieldConfig(),
-                        schedule=schedule, alpha=alpha, eps=eps,
-                        use_pallas=use_pallas, fused=fused,
-                        frontier_mode=frontier_mode,
-                        hop_budget=k, hop_stride=hop_stride)
+        with spans.span(spans.ENGINE):
+            eng = FPPEngine(bg, mode=_ENGINE_MODE[kind],
+                            num_queries=len(sources),
+                            yield_config=yield_config or YieldConfig(),
+                            schedule=schedule, alpha=alpha, eps=eps,
+                            use_pallas=use_pallas, fused=fused,
+                            frontier_mode=frontier_mode,
+                            hop_budget=k, hop_stride=hop_stride)
         res = eng.run(sources, max_visits=max_visits)
         return _normalize(res.values, res.residual, res.edges_processed, {
             "visits": res.stats.visits, "rounds": res.stats.rounds,
-            "blocks_loaded": res.stats.blocks_loaded,
             "modeled_bytes": res.stats.modeled_bytes,
-            "host_syncs": res.stats.host_syncs})
+            "host_syncs": res.stats.host_syncs,
+            "visit_counts": res.stats.visit_counts,
+            "megastep_traces": res.stats.megastep_traces,
+            "chunk_reads": res.stats.chunk_reads})
 
     if backend == "baselines":
         if kind == "ppr":

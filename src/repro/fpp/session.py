@@ -30,12 +30,19 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.graph import BlockGraph, CSRGraph
 from repro.core.partition import partition
 from repro.core.yielding import YieldConfig
 from repro.fpp import backends as _backends
 from repro.fpp import planner as _planner
 from repro.fpp.planner import MemoryModel, Plan
+
+
+#: the counts the ``fpp.run`` span carries at its end: (arg, stats key)
+_RUN_COUNTS = (("visits", "visits"), ("chunks", "host_syncs"),
+               ("chunk_reads", "chunk_reads"),
+               ("megastep_traces", "megastep_traces"))
 
 
 @dataclasses.dataclass
@@ -146,7 +153,7 @@ class FPPSession:
         meth = method or p.method
         variant = weights or ("unit" if unit_weights else "natural")
         key = (bs, meth, variant)
-        with self._prepare_lock:
+        with spans.span(spans.PREPARE), self._prepare_lock:
             if key not in self._prepared:
                 stride = self.kreach_stride if variant == "shift" else None
                 g = reweight(self.graph, variant, stride=stride)
@@ -186,32 +193,45 @@ class FPPSession:
         from repro.core.queries import WEIGHT_VARIANTS
         sources = np.asarray(sources)
         p = self.current_plan
-        bg, perm = self.prepared(block_size=block_size, method=method,
-                                 weights=WEIGHT_VARIANTS.get(kind, "natural"))
-        yc = (yield_config if yield_config is not None else
-              (p.yield_config or _planner.default_yield_config(kind, bg)))
         bk = backend or p.backend
-        if fused is None:
-            # the plan's default applies only where it can: other backends
-            # run their own visit bodies (explicit fused=True still raises).
-            # plan(fused="auto") resolves per kind from committed yardsticks,
-            # falling back to the XLA megastep when this partitioning is
-            # denser than the fused-kernel dmax budget.
-            fused = bk == "engine" and kind != "rw" and p.resolve_fused(
-                kind, dmax=bg.nbr_part.shape[1])
-        out = _backends.run_query(
-            bk, kind, bg, perm[sources],
-            schedule=schedule or p.schedule, yield_config=yc,
-            alpha=alpha, eps=eps, use_pallas=use_pallas, mesh=mesh,
-            max_visits=max_visits,
-            fused=bool(fused) and kind != "rw", frontier_mode=frontier_mode,
-            k=k, hop_stride=(self.kreach_stride if kind == "kreach" else 1.0),
-            length=length, seed=seed)
-        values = out.values[:, perm]          # back to original vertex ids
-        if kind == "cc":
-            values = _backends.canonicalize_cc(values)
-        residual = None if out.residual is None else out.residual[:, perm]
-        return SessionResult(kind=kind, backend=backend or p.backend,
+        with spans.span(spans.RUN, kind=kind, queries=len(sources),
+                        backend=bk) as sp:
+            bg, perm = self.prepared(
+                block_size=block_size, method=method,
+                weights=WEIGHT_VARIANTS.get(kind, "natural"))
+            with spans.span(spans.ENGINE):
+                # the Δ-window is a constant of the engine's programs; its
+                # default scans the whole host block store
+                yc = (yield_config if yield_config is not None else
+                      (p.yield_config
+                       or _planner.default_yield_config(kind, bg)))
+            if fused is None:
+                # the plan's default applies only where it can: other
+                # backends run their own visit bodies (explicit fused=True
+                # still raises).  plan(fused="auto") resolves per kind from
+                # committed yardsticks, falling back to the XLA megastep
+                # when this partitioning is denser than the fused-kernel
+                # dmax budget.
+                fused = bk == "engine" and kind != "rw" and p.resolve_fused(
+                    kind, dmax=bg.nbr_part.shape[1])
+            out = _backends.run_query(
+                bk, kind, bg, perm[sources],
+                schedule=schedule or p.schedule, yield_config=yc,
+                alpha=alpha, eps=eps, use_pallas=use_pallas, mesh=mesh,
+                max_visits=max_visits,
+                fused=bool(fused) and kind != "rw",
+                frontier_mode=frontier_mode, k=k,
+                hop_stride=(self.kreach_stride if kind == "kreach" else 1.0),
+                length=length, seed=seed)
+            with spans.span(spans.FINALIZE):
+                values = out.values[:, perm]  # back to original vertex ids
+                if kind == "cc":
+                    values = _backends.canonicalize_cc(values)
+                residual = (None if out.residual is None
+                            else out.residual[:, perm])
+            spans.note(sp, **{arg: out.stats[key] for arg, key in _RUN_COUNTS
+                              if key in out.stats})
+        return SessionResult(kind=kind, backend=bk,
                              values=values, residual=residual,
                              edges_processed=out.edges_processed,
                              stats=out.stats, sources=sources)

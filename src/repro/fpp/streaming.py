@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import visit as _visit
 from repro.core.engine import FPPEngine
 from repro.core.scheduler import PartitionScheduler
@@ -403,22 +404,27 @@ class StreamingExecutor:
         if limit <= 0:
             self._lane_pending = None   # a stale mask must never be harvested
             return 0
-        st, ms = self._megastep(self.state, jnp.int32(self.visits),
-                                jnp.int32(limit), self._key)
+        chunk = self.host_syncs
+        with spans.span(spans.DISPATCH, chunk=chunk):
+            st, ms = self._megastep(self.state, jnp.int32(self.visits),
+                                    jnp.int32(limit), self._key)
         self.host_syncs += 1
-        v = int(ms.visits)
-        # the mask reflects the chunk-end state even when v == 0 (megastep
-        # recomputes it from the unchanged input state); a chunk that stops
-        # below its limit proves the device is drained — no confirmation
-        # dispatch needed
-        self._lane_pending = np.asarray(ms.lane_pending)
-        self._drained = v < limit
-        if v == 0:
-            return 0
-        self.state = st
-        self._key = ms.key
-        self._edges += _visit.harvest_edges(ms.eq_hi, ms.eq_lo)
-        counts = np.asarray(ms.visit_counts, dtype=np.int64)
+        with spans.span(spans.SYNC, chunk=chunk) as sp:
+            v = int(ms.visits)
+            spans.note(sp, visits=v)
+        with spans.span(spans.HARVEST, chunk=chunk):
+            # the mask reflects the chunk-end state even when v == 0
+            # (megastep recomputes it from the unchanged input state); a
+            # chunk that stops below its limit proves the device is
+            # drained — no confirmation dispatch needed
+            self._lane_pending = np.asarray(ms.lane_pending)
+            self._drained = v < limit
+            if v == 0:
+                return 0
+            self.state = st
+            self._key = ms.key
+            self._edges += _visit.harvest_edges(ms.eq_hi, ms.eq_lo)
+            counts = np.asarray(ms.visit_counts, dtype=np.int64)
         self.modeled_bytes += float(counts @ self.engine._visit_bytes)
         self.visits += v
         return v
@@ -437,7 +443,8 @@ class StreamingExecutor:
                     break
                 self._admit()
                 did = self._chunk(max_visits - (self.visits - start))
-                self._harvest(pending=self._lane_pending)
+                with spans.span(spans.HARVEST):
+                    self._harvest(pending=self._lane_pending)
                 if did == 0 or self._drained:
                     # nothing left pending on device: every unfinished lane
                     # was just harvested; refill from the queue or stop

@@ -83,6 +83,7 @@ from typing import Callable, Dict, Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.scheduler import PartitionScheduler
 from repro.fpp import planner as _planner
 from repro.fpp.session import FPPSession
@@ -554,7 +555,7 @@ class GraphServer:
         if req.kind not in SERVABLE_KINDS:
             raise ValueError(f"kind must be one of {SERVABLE_KINDS}, "
                              f"got {req.kind!r}")
-        with self._lock:
+        with spans.span(spans.SUBMIT) as sp, self._lock:
             if req.graph not in self._sessions:
                 raise ValueError(f"graph {req.graph!r} not registered "
                                  f"(have {sorted(self._sessions)})")
@@ -566,6 +567,7 @@ class GraphServer:
                 self.register_tenant(req.tenant)
             rid = self._next_rid
             self._next_rid += 1
+            spans.note(sp, rid=rid)
             t = _Ticket(rid=rid, req=req, submit_t=self.clock(),
                         submit_round=self.rounds)
             self._tickets[rid] = t
@@ -712,13 +714,14 @@ class GraphServer:
             if self._expired(t, now):
                 self._reject(t, now)
                 continue
-            qid = ex.submit([t.req.source])[0]
-            if ex.queue_depth != 0:
-                raise RuntimeError(
-                    f"admission must be immediate: lane pool reported a "
-                    f"free lane but submit left queue_depth="
-                    f"{ex.queue_depth}")
-            pool.qid_rid[qid] = rid
+            with spans.span(spans.ADMIT, rid=rid):
+                qid = ex.submit([t.req.source])[0]
+                if ex.queue_depth != 0:
+                    raise RuntimeError(
+                        f"admission must be immediate: lane pool reported a "
+                        f"free lane but submit left queue_depth="
+                        f"{ex.queue_depth}")
+                pool.qid_rid[qid] = rid
             t.admit_t = now
             t.admit_round = self.rounds
             self._vtime[tenant] += 1.0 / self._weights[tenant]
@@ -747,16 +750,17 @@ class GraphServer:
         ran — but exact queue wait: the time from submit until the
         delivery lane got to it."""
         t = self._tickets[rid]
-        self._finish(GraphResponse(
-            rid=rid, tenant=t.req.tenant, graph=t.req.graph,
-            kind=t.req.kind, source=t.req.source, status="ok",
-            values=entry.values, residual=entry.residual, stats={
-                "visits": 0, "edges": 0.0, "host_syncs": 0,
-                "queue_wait_s": now - t.submit_t,
-                "queue_wait_rounds": self.rounds - t.submit_round,
-                "latency_s": now - t.submit_t,
-                "cached": True,
-            }))
+        with spans.span(spans.DELIVER, rid=rid):
+            self._finish(GraphResponse(
+                rid=rid, tenant=t.req.tenant, graph=t.req.graph,
+                kind=t.req.kind, source=t.req.source, status="ok",
+                values=entry.values, residual=entry.residual, stats={
+                    "visits": 0, "edges": 0.0, "host_syncs": 0,
+                    "queue_wait_s": now - t.submit_t,
+                    "queue_wait_rounds": self.rounds - t.submit_round,
+                    "latency_s": now - t.submit_t,
+                    "cached": True,
+                }))
 
     def _deliver(self, pool: _LanePool, qids: Iterable[int], now: float):
         """Turn finished executor lanes into responses (+ dedup fan-out)."""
@@ -764,51 +768,53 @@ class GraphServer:
             rid = pool.qid_rid.pop(qid, None)
             if rid is None:
                 continue
-            t = self._tickets[rid]
-            q = pool.exec.queries[qid]
-            stats = {
-                "visits": q.finished_visit - q.admitted_visit,
-                "edges": q.edges,
-                "host_syncs": q.finished_sync - q.admitted_sync,
-                "queue_wait_s": t.admit_t - t.submit_t,
-                "queue_wait_rounds": t.admit_round - t.submit_round,
-                "latency_s": now - t.submit_t,
-            }
-            key = self._dedup_key(t.req)
-            if self._dedup.get(key) == rid:
-                del self._dedup[key]
-            followers = self._followers.pop(rid, [])
-            if followers:
-                stats["fanout"] = len(followers)
-                self._fanout_total += len(followers)
-            if (self.result_cache is not None
-                    and self._sessions.get(pool.graph) is pool.session):
-                # populate once per primary — fan-out followers below ride
-                # the same planes; the session-identity guard means a pool
-                # that somehow outlived an update_graph can never poison
-                # the new epoch (update_graph refuses in-flight work, so
-                # this is belt and braces)
-                self.result_cache.put(self._result_key(t.req),
-                                      q.values, q.residual)
-            self._finish(GraphResponse(
-                rid=rid, tenant=t.req.tenant, graph=pool.graph,
-                kind=pool.kind, source=t.req.source, status="ok",
-                values=q.values, residual=q.residual, stats=stats))
-            for frid in followers:
-                ft = self._tickets[frid]
+            with spans.span(spans.DELIVER, rid=rid):
+                t = self._tickets[rid]
+                q = pool.exec.queries[qid]
+                stats = {
+                    "visits": q.finished_visit - q.admitted_visit,
+                    "edges": q.edges,
+                    "host_syncs": q.finished_sync - q.admitted_sync,
+                    "queue_wait_s": t.admit_t - t.submit_t,
+                    "queue_wait_rounds": t.admit_round - t.submit_round,
+                    "latency_s": now - t.submit_t,
+                }
+                key = self._dedup_key(t.req)
+                if self._dedup.get(key) == rid:
+                    del self._dedup[key]
+                followers = self._followers.pop(rid, [])
+                if followers:
+                    stats["fanout"] = len(followers)
+                    self._fanout_total += len(followers)
+                if (self.result_cache is not None
+                        and self._sessions.get(pool.graph) is pool.session):
+                    # populate once per primary — fan-out followers below
+                    # ride the same planes; the session-identity guard
+                    # means a pool that somehow outlived an update_graph
+                    # can never poison the new epoch (update_graph refuses
+                    # in-flight work, so this is belt and braces)
+                    self.result_cache.put(self._result_key(t.req),
+                                          q.values, q.residual)
                 self._finish(GraphResponse(
-                    rid=frid, tenant=ft.req.tenant, graph=pool.graph,
-                    kind=pool.kind, source=ft.req.source, status="ok",
-                    values=q.values, residual=q.residual, stats={
-                        # the lane's work billed to every requester
-                        "visits": stats["visits"], "edges": q.edges,
-                        "host_syncs": stats["host_syncs"],
-                        "queue_wait_s": max(0.0, t.admit_t - ft.submit_t),
-                        "queue_wait_rounds": max(
-                            0, t.admit_round - ft.submit_round),
-                        "latency_s": now - ft.submit_t,
-                        "coalesced": True,
-                    }))
+                    rid=rid, tenant=t.req.tenant, graph=pool.graph,
+                    kind=pool.kind, source=t.req.source, status="ok",
+                    values=q.values, residual=q.residual, stats=stats))
+                for frid in followers:
+                    ft = self._tickets[frid]
+                    self._finish(GraphResponse(
+                        rid=frid, tenant=ft.req.tenant, graph=pool.graph,
+                        kind=pool.kind, source=ft.req.source, status="ok",
+                        values=q.values, residual=q.residual, stats={
+                            # the lane's work billed to every requester
+                            "visits": stats["visits"], "edges": q.edges,
+                            "host_syncs": stats["host_syncs"],
+                            "queue_wait_s": max(0.0,
+                                                t.admit_t - ft.submit_t),
+                            "queue_wait_rounds": max(
+                                0, t.admit_round - ft.submit_round),
+                            "latency_s": now - ft.submit_t,
+                            "coalesced": True,
+                        }))
 
     def _queue_delivery(self, pool: _LanePool, qids: List[int]):
         """Hand finished lanes to the delivery thread (inline fallback
