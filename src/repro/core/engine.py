@@ -66,6 +66,8 @@ class VisitStats(NamedTuple):
     visit_counts: Optional[np.ndarray] = None  # [P] int64 visits/partition
     megastep_traces: int = 0  # times this run traced its megastep
     chunk_reads: int = 0      # device->host reads at chunk (visit) bounds
+    relax_width: int = 0      # candidates per relaxed output: W (pull-ELL
+    #                           view) or B (dense tile)
 
 
 # ---------------------------------------------------------------------------
@@ -74,7 +76,7 @@ class VisitStats(NamedTuple):
 
 #: BlockGraph fields staged onto the device
 _STAGED = ("blocks", "row_nnz", "nbr_blk", "nbr_part", "diag_blk", "deg",
-           "vmask")
+           "vmask", "ell_src", "ell_w")
 _stage_lock = threading.Lock()
 
 
@@ -95,7 +97,8 @@ def _staged(bg: BlockGraph) -> dict:
 
 @functools.partial(jax.tree_util.register_dataclass,
                    data_fields=list(_STAGED) + ["edge_budget"],
-                   meta_fields=["num_parts", "block_size", "dmax"])
+                   meta_fields=["num_parts", "block_size", "dmax",
+                                "ell_width"])
 @dataclasses.dataclass
 class DeviceGraph:
     """BlockGraph arrays staged onto device once (the in-memory graph).
@@ -109,10 +112,13 @@ class DeviceGraph:
     diag_blk: jax.Array   # [P] i32
     deg: jax.Array        # [P, B] i32
     vmask: jax.Array      # [P, B] bool
+    ell_src: jax.Array    # [nblk, B, W] i32 pull-ELL source rows (W may be 0)
+    ell_w: jax.Array      # [nblk, B, W] f32 their weights, +inf padding
     edge_budget: jax.Array  # [P] f32 per-query edge budget per visit
     num_parts: int
     block_size: int
     dmax: int
+    ell_width: int        # W; 0 when the graph has no pull-ELL view
 
     @staticmethod
     def build(bg: BlockGraph, yc: YieldConfig, num_queries: int) -> "DeviceGraph":
@@ -124,6 +130,7 @@ class DeviceGraph:
             num_parts=bg.num_parts,
             block_size=bg.block_size,
             dmax=bg.nbr_blk.shape[1],
+            ell_width=bg.ell_width,
         )
 
 
@@ -206,7 +213,8 @@ class FPPEngine:
         self.max_rounds = max_rounds
         # fused visits run the whole body inside one pallas_call, so the
         # algebra must keep its XLA relax/spread — a pallas_call nested in
-        # a Pallas kernel body would not lower
+        # a Pallas kernel body would not lower.  use_pallas keeps the
+        # dense Pallas relax for every block (no pull-ELL form).
         if mode == "push":
             spread = (minplus_ops.masked_matmul_pallas
                       if use_pallas and not fused else None)
@@ -226,6 +234,9 @@ class FPPEngine:
             self.dg, self.algebra, max_rounds, policy=schedule,
             K=self.k_visits, fused=self.fused,
             frontier_mode=self.frontier_mode)
+        # the fused kernel contracts dense tiles whatever the algebra has
+        self.relax_width = (bg.block_size if self.fused
+                            else _visit.relax_width(self.dg, self.algebra))
         # modeled HBM traffic per visit: diagonal block + touched out-blocks +
         # two state tiles — the cache-miss analogue used by fig10.
         B = bg.block_size
@@ -316,7 +327,7 @@ class FPPEngine:
             visits=visits, rounds=rounds,
             modeled_bytes=float(counts @ self._visit_bytes),
             host_syncs=syncs, visit_counts=counts, megastep_traces=traces,
-            chunk_reads=reads)
+            chunk_reads=reads, relax_width=self.relax_width)
         with spans.span(spans.FINALIZE):
             return self._finalize(state, edges, stats, order)
 
@@ -350,7 +361,7 @@ class FPPEngine:
         stats = VisitStats(visits=visits, rounds=rounds,
                            modeled_bytes=float(counts @ self._visit_bytes),
                            host_syncs=visits, visit_counts=counts,
-                           chunk_reads=reads)
+                           chunk_reads=reads, relax_width=self.relax_width)
         with spans.span(spans.FINALIZE):
             return self._finalize(state, edges, stats, order)
 

@@ -92,6 +92,17 @@ class BlockGraph:
     row_nnz     int32   [nblk, B]     out-degree of each local row within block k
     deg         int32   [P, B]        total out-degree of each vertex (padded: 0)
     vmask       bool    [P, B]        True for real (non padding) vertices
+    ell_src     int32   [nblk, B, W]  pull-ELL view of ``blocks``: the source rows
+                                      ``u`` with a finite ``blocks[k, u, v]``,
+                                      listed per destination column ``v``
+                                      (padding: row 0)
+    ell_w       float32 [nblk, B, W]  their weights, the same f32 values as
+                                      ``blocks`` (padding: +inf)
+
+    ``W`` is the widest destination column over all blocks.  The view is
+    built only when ``W <= B * ELL_MAX_WIDTH_SHARE``; otherwise (hub-heavy
+    graphs, whose columns fill up) both arrays have width 0 and every
+    relaxation reads the dense tiles.
     """
 
     blocks: np.ndarray
@@ -103,6 +114,8 @@ class BlockGraph:
     row_nnz: np.ndarray
     deg: np.ndarray
     vmask: np.ndarray
+    ell_src: np.ndarray
+    ell_w: np.ndarray
     block_size: int
     num_parts: int
     n: int                 # real vertex count (pre-padding)
@@ -111,6 +124,11 @@ class BlockGraph:
     @property
     def n_padded(self) -> int:
         return self.num_parts * self.block_size
+
+    @property
+    def ell_width(self) -> int:
+        """``W`` of the pull-ELL view; 0 when the view was not built."""
+        return self.ell_src.shape[2]
 
     @staticmethod
     def from_csr(g: CSRGraph, block_size: int) -> "BlockGraph":
@@ -125,19 +143,18 @@ class BlockGraph:
         diag_pairs = np.arange(P, dtype=np.int64) * P + np.arange(P, dtype=np.int64)
         uniq = np.unique(np.concatenate([pair, diag_pairs]))
         nblk = int(uniq.size)
-        pair_to_blk = {int(pv): k for k, pv in enumerate(uniq)}
         blk_src = (uniq // P).astype(np.int32)
         blk_dst = (uniq % P).astype(np.int32)
         blocks = np.full((nblk, B, B), INF, dtype=np.float32)
-        if src.size:
-            bk = np.array([pair_to_blk[int(pv)] for pv in pair], dtype=np.int64)
-            ul = (src % B).astype(np.int64)
-            vl = (dst % B).astype(np.int64)
-            # duplicate edges already removed in CSR; direct assignment keeps min
-            flat = blocks.reshape(nblk, B * B)
-            np.minimum.at(flat, (bk, ul * B + vl), w.astype(np.float32))
-        diag_blk = np.array([pair_to_blk[int(p * P + p)] for p in range(P)],
-                            dtype=np.int32)
+        bk = np.searchsorted(uniq, pair)
+        ul = (src % B).astype(np.int64)
+        vl = (dst % B).astype(np.int64)
+        w = w.astype(np.float32)
+        # duplicate edges (a CSR built with dedup=False) keep their minimum
+        flat = blocks.reshape(nblk, B * B)
+        np.minimum.at(flat, (bk, ul * B + vl), w)
+        ell_src, ell_w = _pull_ell(nblk, B, bk, ul, vl, w)
+        diag_blk = np.searchsorted(uniq, diag_pairs).astype(np.int32)
         # neighbor lists excluding the diagonal
         nbrs: list[list[int]] = [[] for _ in range(P)]
         for k in range(nblk):
@@ -159,18 +176,62 @@ class BlockGraph:
         return BlockGraph(blocks=blocks, blk_src=blk_src, blk_dst=blk_dst,
                           nbr_blk=nbr_blk, nbr_part=nbr_part, diag_blk=diag_blk,
                           row_nnz=row_nnz, deg=deg, vmask=vmask,
+                          ell_src=ell_src, ell_w=ell_w,
                           block_size=B, num_parts=P, n=g.n, m=g.m)
 
     def nbytes(self) -> int:
         return sum(a.nbytes for a in
                    (self.blocks, self.nbr_blk, self.nbr_part, self.diag_blk,
-                    self.row_nnz, self.deg, self.vmask))
+                    self.row_nnz, self.deg, self.vmask, self.ell_src,
+                    self.ell_w))
 
     def part_of(self, v: int) -> int:
         return int(v) // self.block_size
 
     def local_of(self, v: int) -> int:
         return int(v) % self.block_size
+
+
+#: the widest pull-ELL column, as a share of B, for which the view is built
+#: and the XLA megastep relaxes over it instead of the dense tiles.  On a
+#: v5e at Q = 64, B = 1024 a relaxation over the view took 4.9 + 2.04 W us
+#: (13.1, 37.1 and 135.4 us at W = 4, 16, 64) and one over the dense tile
+#: 92.0 us, so the view stops paying near W = 43, a share of 0.042; 1/32
+#: keeps a quarter of margin (PERF.md §6).  The dense cost per output
+#: grows with B and the view's with W, so the rule is a share of B.
+ELL_MAX_WIDTH_SHARE = 1 / 32
+
+
+def _pull_ell(nblk: int, B: int, bk, ul, vl, w):
+    """``(ell_src, ell_w)`` [nblk, B, W] from the block-local edge arrays.
+
+    Entries are sorted by (block, destination column, source row); a
+    duplicate (block, u, v) keeps its minimum weight, as the dense store
+    does, so the view holds exactly the finite values of ``blocks``.
+    Width 0 when the widest column exceeds ``B * ELL_MAX_WIDTH_SHARE``.
+    """
+    key = (bk * B + vl) * B + ul
+    order = np.argsort(key, kind="stable")
+    key, w = key[order], w[order]
+    new = np.ones(key.size, dtype=bool)
+    new[1:] = key[1:] != key[:-1]
+    starts = np.flatnonzero(new)
+    if key.size:
+        w = np.minimum.reduceat(w, starts)
+    col, u = key[starts] // B, key[starts] % B    # col = block * B + v
+    first = np.ones(col.size, dtype=bool)
+    first[1:] = col[1:] != col[:-1]
+    pos = np.arange(col.size)
+    rank = pos - np.maximum.accumulate(np.where(first, pos, 0))
+    width = int(rank.max(initial=0)) + 1
+    if width > B * ELL_MAX_WIDTH_SHARE:
+        width = 0
+    ell_src = np.zeros((nblk * B, width), dtype=np.int32)
+    ell_w = np.full((nblk * B, width), INF, dtype=np.float32)
+    if width:
+        ell_src[col, rank] = u
+        ell_w[col, rank] = w
+    return ell_src.reshape(nblk, B, width), ell_w.reshape(nblk, B, width)
 
 
 def vmem_block_size(vmem_bytes: int = 96 * 1024 * 1024,

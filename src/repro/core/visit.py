@@ -65,6 +65,14 @@ class PushCarry(NamedTuple):
     acc: jax.Array      # [Q, B] accumulated pushed mass (emission payload)
 
 
+class EllOps(NamedTuple):
+    """An algebra's ``step`` and ``contrib`` over a block's pull-ELL slice
+    ``(src [B, W], w [B, W])`` (``BlockGraph.ell_src`` / ``ell_w``) in
+    place of its dense ``[B, B]`` tile; same signatures otherwise."""
+    step: Callable
+    contrib: Callable
+
+
 @dataclasses.dataclass(frozen=True)
 class VisitAlgebra:
     """Mode-specific operators; everything else is the shared skeleton.
@@ -96,6 +104,10 @@ class VisitAlgebra:
     #: the fused Pallas visit kernel rebuilds the inner-round math from these
     #: (kernel bodies can't call back into the closure-captured XLA ops).
     params: Tuple[Tuple[str, float], ...] = ()
+    #: the same operators over pull-ELL slices; None where the algebra
+    #: contracts dense tiles only.  The XLA visit body reads the view when
+    #: this is set and the graph has one (:func:`uses_ell`).
+    ell: Optional[EllOps] = None
 
     @property
     def num_planes(self) -> int:
@@ -109,6 +121,11 @@ def minplus_algebra(window: float, relax: Optional[Callable] = None,
                     strict: bool = False) -> VisitAlgebra:
     """SSSP/BFS family: ops combine by ``min``, relax is min-plus matmul.
 
+    With the default XLA ``relax`` the algebra also relaxes over pull-ELL
+    slices (``ell``, through ``kernels/minplus/ops.minplus_ell``,
+    bit-identical); a caller's own dense ``relax`` (the Pallas kernel)
+    is kept for every block, so such an algebra has no ``ell``.
+
     ``strict=True`` makes an op pend only when it *strictly* improves the
     plane value (``buf < d`` instead of ``buf <= d``).  Positive-weight
     kinds terminate either way — a cycle re-sends values strictly above
@@ -118,6 +135,7 @@ def minplus_algebra(window: float, relax: Optional[Callable] = None,
     re-emitted).  Strictness drops an op that cannot change anything,
     which is exact for an idempotent min fixpoint.
     """
+    dense_only = relax is not None
     relax = relax or minplus_ops.minplus
     lt = jnp.less if strict else jnp.less_equal
 
@@ -142,13 +160,21 @@ def minplus_algebra(window: float, relax: Optional[Callable] = None,
         return (carry.pending & (carry.d <= carry.alpha + window)
                 & (eq.astype(jnp.float32) < budget)[:, None])
 
-    def step(carry, act, w_pp, deg_row):
-        srcs = jnp.where(act, carry.d, INF)
-        nd = relax(srcs, w_pp)
-        improved = nd < carry.d
-        return MinplusCarry(d=jnp.minimum(carry.d, nd),
-                            pending=(carry.pending & ~act) | improved,
-                            emit=carry.emit | act, alpha=carry.alpha)
+    def step_over(contract):
+        def step(carry, act, tile, deg_row):
+            srcs = jnp.where(act, carry.d, INF)
+            nd = contract(srcs, tile)
+            improved = nd < carry.d
+            return MinplusCarry(d=jnp.minimum(carry.d, nd),
+                                pending=(carry.pending & ~act) | improved,
+                                emit=carry.emit | act, alpha=carry.alpha)
+        return step
+
+    ell = None
+    if not dense_only:
+        def contrib_ell(x, tile):
+            return minplus_ops.minplus_ell(x, *tile)
+        ell = EllOps(step=step_over(contrib_ell), contrib=contrib_ell)
 
     def finish(carry, deg_row):
         keep = jnp.where(carry.pending, carry.d, INF)
@@ -157,14 +183,15 @@ def minplus_algebra(window: float, relax: Optional[Callable] = None,
     return VisitAlgebra(
         name="minplus", identity=float(np.inf), source_value=0.0,
         plane_init=(float(np.inf),), combine=jnp.minimum,
-        begin=begin, active=active, step=step,
+        begin=begin, active=active, step=step_over(relax),
         emit_payload=lambda carry: jnp.where(carry.emit, carry.d, INF),
         emit_mask=lambda carry: carry.emit,
         contrib=relax,
         scatter=lambda buf, idx, cands: buf.at[idx].min(cands),
         pending=pending, prio_of=prio_of, finish=finish,
         params=(("window", float(window)),
-                ("strict", 1.0 if strict else 0.0)))
+                ("strict", 1.0 if strict else 0.0)),
+        ell=ell)
 
 
 def push_algebra(alpha: float, eps: float,
@@ -305,6 +332,19 @@ def init_engine_state(algebra: VisitAlgebra, dg, sources: np.ndarray,
 # generic visit kernel (single-device engine)
 
 
+def uses_ell(dg, algebra: VisitAlgebra) -> bool:
+    """Whether the XLA visit body relaxes over the graph's pull-ELL view:
+    the algebra has an ELL form and the graph has the view (its widest
+    column is small against B, ``core/graph.ELL_MAX_WIDTH_SHARE``)."""
+    return algebra.ell is not None and dg.ell_width > 0
+
+
+def relax_width(dg, algebra: VisitAlgebra) -> int:
+    """Candidates the XLA visit body's relaxation reduces over per output:
+    ``W`` over the pull-ELL view, ``B`` over the dense tile."""
+    return dg.ell_width if uses_ell(dg, algebra) else dg.block_size
+
+
 def _make_visit_body(dg, algebra: VisitAlgebra, max_rounds: int) -> Callable:
     """The unjitted visit body (Alg. 2 lines 6-16): apply + relax until
     yield, then emit one combined contribution per neighbor partition.
@@ -313,13 +353,28 @@ def _make_visit_body(dg, algebra: VisitAlgebra, max_rounds: int) -> Callable:
     this visit's per-query edge count (int32 [Q], exact).  :func:`make_visit`
     jits it for per-visit host dispatch; :func:`make_megastep` runs it inside
     a device-resident ``lax.while_loop``.
+
+    The relaxation and the emission contract either each block's dense
+    ``[B, B]`` tile or its pull-ELL slice (:func:`uses_ell`); the bits are
+    the same either way.
     """
     P = dg.num_parts
 
-    def block(k):
+    def slice_of(store, k):
         # one dynamic slice per block: a gather over the block store lets
         # XLA split (and so copy) the whole store inside the visit loop
-        return jax.lax.dynamic_index_in_dim(dg.blocks, k, keepdims=False)
+        return jax.lax.dynamic_index_in_dim(store, k, keepdims=False)
+
+    if uses_ell(dg, algebra):
+        step, contrib = algebra.ell
+
+        def block(k):
+            return slice_of(dg.ell_src, k), slice_of(dg.ell_w, k)
+    else:
+        step, contrib = algebra.step, algebra.contrib
+
+        def block(k):
+            return slice_of(dg.blocks, k)
 
     def visit(state: VisitState, p: jax.Array, counter: jax.Array):
         # the scopes name the visit's phases in the compiled program's op
@@ -343,7 +398,7 @@ def _make_visit_body(dg, algebra: VisitAlgebra, max_rounds: int) -> Callable:
             act = algebra.active(carry, deg_p, eq, budget)
             eq = eq + jnp.sum(jnp.where(act, nnz_pp[None, :], 0), axis=1,
                               dtype=jnp.int32)
-            return algebra.step(carry, act, w_pp, deg_p), eq, rounds + 1
+            return step(carry, act, w_pp, deg_p), eq, rounds + 1
 
         eq0 = jnp.zeros(buf_row.shape[0], dtype=jnp.int32)
         with jax.named_scope("visit/relax"):
@@ -366,8 +421,9 @@ def _make_visit_body(dg, algebra: VisitAlgebra, max_rounds: int) -> Callable:
         blk0 = jnp.where(valid, dg.nbr_blk[p], 0)
         j0 = jnp.where(valid, parts, 0)                # clamped gather index
         jj = jnp.where(valid, parts, P)                # trash row for padding
-        w_nb = jnp.stack([block(blk0[s]) for s in range(blk0.shape[0])])
-        cands = jax.vmap(lambda w: algebra.contrib(payload, w))(
+        w_nb = jax.tree.map(lambda *t: jnp.stack(t),
+                            *[block(blk0[s]) for s in range(blk0.shape[0])])
+        cands = jax.vmap(lambda w: contrib(payload, w))(
             w_nb)                                      # [dmax, Q, B]
         cands = jnp.where(valid[:, None, None], cands, algebra.identity)
         nnz_sl = jnp.where(valid[:, None], dg.row_nnz[blk0], 0)  # [dmax, B]

@@ -149,7 +149,7 @@ def run_query(backend: str, kind: str, bg: BlockGraph, sources: np.ndarray,
         return _rw_result(res, {"supersteps": res.visits})
 
     if backend == "engine":
-        with spans.span(spans.ENGINE):
+        with spans.span(spans.ENGINE) as sp:
             eng = FPPEngine(bg, mode=_ENGINE_MODE[kind],
                             num_queries=len(sources),
                             yield_config=yield_config or YieldConfig(),
@@ -157,6 +157,7 @@ def run_query(backend: str, kind: str, bg: BlockGraph, sources: np.ndarray,
                             use_pallas=use_pallas, fused=fused,
                             frontier_mode=frontier_mode,
                             hop_budget=k, hop_stride=hop_stride)
+            spans.note(sp, relax_width=eng.relax_width)
         res = eng.run(sources, max_visits=max_visits)
         return _normalize(res.values, res.residual, res.edges_processed, {
             "visits": res.stats.visits, "rounds": res.stats.rounds,
@@ -164,7 +165,8 @@ def run_query(backend: str, kind: str, bg: BlockGraph, sources: np.ndarray,
             "host_syncs": res.stats.host_syncs,
             "visit_counts": res.stats.visit_counts,
             "megastep_traces": res.stats.megastep_traces,
-            "chunk_reads": res.stats.chunk_reads})
+            "chunk_reads": res.stats.chunk_reads,
+            "relax_width": res.stats.relax_width})
 
     if backend == "baselines":
         if kind == "ppr":

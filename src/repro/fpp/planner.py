@@ -295,7 +295,7 @@ def adjacency_estimate(g: CSRGraph, block_size: int,
     """HBM bytes of the dense block store at ``block_size``, estimated
     before partitioning: about one neighborhood (the diagonal block plus
     :func:`est_dmax` boundary blocks) per partition.  Once a graph is
-    partitioned its ``BlockGraph.blocks.nbytes`` is the exact figure."""
+    partitioned its ``BlockGraph.nbytes()`` is the exact figure."""
     b = int(block_size)
     nparts = -(-g.n // b)
     return nparts * (1 + est_dmax(g, b)) * b * b * mem.dtype_bytes

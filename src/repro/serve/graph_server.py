@@ -839,7 +839,7 @@ class GraphServer:
             "capacity": pool.capacity, "mem": plan.mem,
             "n_vertices": pool.session.graph.n,
             "block_size": pool.exec.bg.block_size,
-            "adjacency_bytes": pool.exec.bg.blocks.nbytes,
+            "adjacency_bytes": pool.exec.bg.nbytes(),
             "min_capacity": 1, "max_capacity": self.max_capacity,
         }))
         if hint < 1:

@@ -115,6 +115,14 @@ def test_megastep_traces_once_per_run(session, traced):
     assert again.stats["megastep_traces"] == 1
 
 
+def test_engine_span_notes_the_relax_width(traced):
+    res, found = traced
+    widths = [f[4]["relax_width"] for f in found
+              if f[0] == spans.ENGINE and "relax_width" in f[4]]
+    # B = 16 is too narrow for the pull-ELL view: the dense tile ran
+    assert widths == [res.stats["relax_width"]] == [16]
+
+
 def test_init_state_span_carries_the_state_bytes(traced):
     _, found = traced
     init, = [f for f in found if f[0] == spans.INIT_STATE]

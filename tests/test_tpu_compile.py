@@ -91,20 +91,24 @@ def test_frontier_kernel_compiles(shape):
         b, d, delta=1.0, interpret=False), shape((Q, B)), shape((Q, B)))
 
 
-def _device_graph(shape, dmax=DMAX, p=P, b=B, nblk=NBLK):
-    """(stand-in graph for building, graph of shapes for lowering)."""
+def _device_graph(shape, dmax=DMAX, p=P, b=B, nblk=NBLK, ell_width=0):
+    """(stand-in graph for building, graph of shapes for lowering);
+    ``ell_width`` > 0 gives the graph a pull-ELL view of that width."""
     from repro.core.engine import DeviceGraph
     i32 = jnp.int32
     meta = DeviceGraph(
         blocks=None, row_nnz=None, nbr_blk=None,
         nbr_part=np.full((p, dmax), -1, np.int32), diag_blk=None, deg=None,
-        vmask=None, edge_budget=None, num_parts=p, block_size=b, dmax=dmax)
+        vmask=None, ell_src=None, ell_w=None, edge_budget=None,
+        num_parts=p, block_size=b, dmax=dmax, ell_width=ell_width)
     shapes = DeviceGraph(
         blocks=shape((nblk, b, b)), row_nnz=shape((nblk, b), i32),
         nbr_blk=shape((p, dmax), i32), nbr_part=shape((p, dmax), i32),
         diag_blk=shape((p,), i32), deg=shape((p, b), i32),
-        vmask=shape((p, b), jnp.bool_), edge_budget=shape((p,)),
-        num_parts=p, block_size=b, dmax=dmax)
+        vmask=shape((p, b), jnp.bool_),
+        ell_src=shape((nblk, b, ell_width), i32),
+        ell_w=shape((nblk, b, ell_width)), edge_budget=shape((p,)),
+        num_parts=p, block_size=b, dmax=dmax, ell_width=ell_width)
     return meta, shapes
 
 
@@ -142,19 +146,23 @@ def test_fused_visit_kernel_compiles(shape, kind, mode, p, b, nblk):
                      shape((), jnp.int32))
 
 
-@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("fused,ell_width", [(False, 0), (True, 0),
+                                              (False, 4)],
+                         ids=["False", "True", "ell"])
 def test_megastep_compiles_without_copying_the_block_store(topo, shape,
-                                                           fused):
+                                                           fused, ell_width):
     """The engine's K-visit megastep at the smoke shapes, built as the
     engine builds it on a TPU backend: the fused body lowers to the Mosaic
-    kernel (never interpret mode), and neither body copies the block
-    store (a gather over it used to make XLA split the whole store inside
-    the visit loop)."""
+    kernel (never interpret mode), and no body copies the block store (a
+    gather over it used to make XLA split the whole store inside the
+    visit loop) or, relaxing over the pull-ELL view, the view."""
     from repro.core import visit as _visit
-    meta, graph = _device_graph(shape, dmax=6)
+    meta, graph = _device_graph(shape, dmax=6, ell_width=ell_width)
+    alg = _algebra("minplus")
+    assert _visit.uses_ell(graph, alg) == (ell_width > 0)
     with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
             mock.patch.object(jax, "devices", lambda *a: topo.devices):
-        ms = _visit.make_megastep(meta, _algebra("minplus"), B, K=64,
+        ms = _visit.make_megastep(meta, alg, B, K=64,
                                   fused=fused, harvest_mask=True)
     state = _visit.VisitState((shape((P, Q, B)),), shape((P + 1, Q, B)),
                               shape((P,)), shape((P,), jnp.int32),
@@ -165,7 +173,11 @@ def test_megastep_compiles_without_copying_the_block_store(topo, shape,
                            shape(key.shape, key.dtype)).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == fused
     block_store = NBLK * B * B * 4
-    assert compiled.memory_analysis().temp_size_in_bytes < block_store // 8
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < block_store // 8
+    if ell_width:
+        # the view is 25 MB here; the ELL megastep's temp about 1.6 MB
+        assert temp < NBLK * B * ell_width * 8
 
 
 @pytest.mark.parametrize("kind", ["sssp", "ppr"])
