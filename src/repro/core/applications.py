@@ -17,6 +17,7 @@ from typing import Optional
 import numpy as np
 
 from repro.core.graph import CSRGraph
+from repro.core.support import SupportRows
 from repro.core.yielding import YieldConfig
 
 
@@ -113,44 +114,95 @@ def landmark_labeling(g: CSRGraph, landmarks: np.ndarray,
 # Network community profile (via many PPRs + sweep cuts)
 
 
-def sweep_conductance(g: CSRGraph, p: np.ndarray):
-    """Sweep cut over one PPR vector. Returns (sizes, conductances) along the
-    sweep prefix order (deg-normalized, ACL standard)."""
-    deg = g.out_degree().astype(np.float64)
-    support = np.flatnonzero(p > 0)
-    if support.size < 2:
+def support_of(pvals):
+    """The supports ``{v : p(v) > 0}`` of the rows of ``pvals`` — a
+    ``[Q, n]`` array (one scan of the whole batch) or
+    :class:`~repro.core.support.SupportRows` — as ``(flat, p)``: ascending
+    flat indices ``row * n + v`` and the values there."""
+    if isinstance(pvals, SupportRows):
+        keep = pvals.data > 0
+        return ((pvals.rows() * pvals.n + pvals.indices)[keep],
+                pvals.data[keep])
+    flat = np.flatnonzero(pvals > 0)
+    return flat, pvals[np.divmod(flat, pvals.shape[1])]
+
+
+def _sweeps(g: CSRGraph, shape, flat: np.ndarray, p: np.ndarray):
+    """Every row's sweep at once, reading only the supports (``flat`` and
+    ``p``, from :func:`support_of`) and their CSR rows: O(vol(supports)
+    log).
+
+    Returns ``(sizes, cond)``, one entry per sweep prefix of each row whose
+    support holds two vertices or more, rows ascending and each row in
+    sweep order.  Volumes and cuts are integer-valued float64 sums, so
+    they are exact whatever order they are added in."""
+    q, n = shape
+    if flat.size == 0:
         return np.array([], dtype=np.int64), np.array([])
-    score = p[support] / np.maximum(deg[support], 1.0)
-    order = support[np.argsort(-score, kind="stable")]
-    rank = np.full(g.n, np.iinfo(np.int64).max, dtype=np.int64)
-    rank[order] = np.arange(order.size)
-    vol = np.cumsum(deg[order])
-    src, dst, _ = g.edges()
-    both = (rank[src] < order.size) & (rank[dst] < order.size)
-    eranks = np.maximum(rank[src[both]], rank[dst[both]])
-    internal = np.bincount(eranks, minlength=order.size).astype(np.float64)
-    cut = vol - np.cumsum(internal)
-    m2 = float(deg.sum())
+    row, v = np.divmod(flat, n)
+    counts = np.bincount(row, minlength=q)
+    start = np.cumsum(counts) - counts          # first slot of each row
+    lo, lens = g.indptr[v], g.indptr[v + 1] - g.indptr[v]
+    deg = lens.astype(np.float64)
+    score = p / np.maximum(deg, 1.0)
+    # sweep order: by row, then by -p/deg; lexsort is stable, so ties keep
+    # ascending vertex ids
+    by_score = np.lexsort((-score, row))
+    rank = np.empty(flat.size, dtype=np.int64)  # slot -> sweep position
+    rank[by_score] = np.arange(flat.size)
+    # each support vertex's out-arcs: a head in the same row's support is
+    # an internal arc, counted at the later of its two sweep positions
+    tail = np.repeat(np.arange(flat.size), lens)
+    first = np.cumsum(lens) - lens
+    key = row[tail] * n + g.indices[np.arange(tail.size)
+                                    + np.repeat(lo - first, lens)]
+    slot = np.minimum(np.searchsorted(flat, key), flat.size - 1)
+    inside = flat[slot] == key
+    eranks = np.maximum(rank[tail[inside]], rank[slot[inside]])
+    internal = np.bincount(eranks, minlength=flat.size).astype(np.float64)
+
+    def within_row(x):                          # per-row running sums
+        run = np.cumsum(x)
+        before = np.where(start > 0, run[start - 1], 0.0)
+        return run - np.repeat(before, counts)
+
+    vol = within_row(deg[by_score])
+    cut = vol - within_row(internal)
+    m2 = float(g.indptr[-1])
     denom = np.minimum(vol, m2 - vol)
     keep = denom > 0
-    cond = np.full(order.size, np.inf)
+    cond = np.full(flat.size, np.inf)
     cond[keep] = cut[keep] / denom[keep]
-    sizes = np.arange(1, order.size + 1)
-    return sizes, cond
+    sorted_row = row[by_score]
+    sizes = np.arange(flat.size) - start[sorted_row] + 1
+    swept = counts[sorted_row] >= 2
+    return sizes[swept], cond[swept]
 
 
-def ncp_profile(g: CSRGraph, pvals: np.ndarray,
-                max_size: Optional[int] = None) -> np.ndarray:
-    """Min conductance per log2 cluster-size bin over PPR vectors [Q, n]."""
+def sweep_conductance(g: CSRGraph, p: np.ndarray):
+    """Sweep cut over one PPR vector. Returns (sizes, conductances) along the
+    sweep prefix order (deg-normalized, ACL standard): the support ordered
+    by ``-p/deg``, ties in ascending vertex id.  Bit for bit
+    ``oracles.sweep_conductance_ref``, which scans the whole graph."""
+    p = np.asarray(p)[None]
+    return _sweeps(g, p.shape, *support_of(p))
+
+
+def ncp_profile(g: CSRGraph, pvals, max_size: Optional[int] = None, *,
+                support=None) -> np.ndarray:
+    """Min conductance per log2 cluster-size bin over PPR vectors: a
+    ``[Q, n]`` array or :class:`~repro.core.support.SupportRows`.
+
+    Each row costs its support's volume, not O(n + m): ``support`` is
+    :func:`support_of` ``(pvals)``, made here when the caller has none."""
     max_size = max_size or g.n
     nbins = int(np.ceil(np.log2(max_size))) + 1
     best = np.full(nbins, np.inf)
-    for qi in range(pvals.shape[0]):
-        sizes, cond = sweep_conductance(g, pvals[qi])
-        if sizes.size == 0:
-            continue
-        bins = np.minimum(np.log2(sizes).astype(np.int64), nbins - 1)
-        np.minimum.at(best, bins, cond)
+    if support is None:
+        support = support_of(pvals)
+    sizes, cond = _sweeps(g, pvals.shape, *support)
+    bins = np.minimum(np.log2(sizes).astype(np.int64), nbins - 1)
+    np.minimum.at(best, bins, cond)
     return best
 
 

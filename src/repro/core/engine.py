@@ -45,6 +45,7 @@ from repro.core import visit as _visit
 from repro.core.graph import BlockGraph
 from repro.core.oracles import decode_kreach
 from repro.core.scheduler import PartitionScheduler
+from repro.core.support import SupportRows
 from repro.core.visit import (VisitAlgebra, VisitState, minplus_algebra,
                               push_algebra)
 from repro.core.yielding import YieldConfig
@@ -68,6 +69,8 @@ class VisitStats(NamedTuple):
     chunk_reads: int = 0      # device->host reads at chunk (visit) bounds
     relax_width: int = 0      # candidates per relaxed output: W (pull-ELL
     #                           view) or B (dense tile)
+    lane_visits: int = 0      # query lanes that processed an edge, summed
+    #                           over the visits (<= visits * num_queries)
 
 
 # ---------------------------------------------------------------------------
@@ -162,13 +165,40 @@ def init_push_state(dg: DeviceGraph, sources: np.ndarray,
     return _visit.init_engine_state(push_algebra(alpha, eps), dg, sources)
 
 
+#: the touched vertices one lane of a support delivery carries; a batch with
+#: a wider lane falls back to reading its planes whole.  A PPR lane at
+#: alpha 0.15, eps 1e-4 on a road lattice touches a few hundred.
+SUPPORT_SLOTS = 4096
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _touched(p, r, buf, n: int, slots: int):
+    """Each lane's vertices (reordered ids) whose mass or residual is
+    nonzero, the residual with its buffered ops folded in as the dense
+    finalize folds them: ``(count [Q], ids [Q, slots], p, r)``, the first
+    ``count`` slots of each row ascending and valid."""
+    P, Q, B = p.shape
+    r = r + buf[:P]
+    hit = ((p != 0) | (r != 0)).transpose(1, 0, 2).reshape(Q, P * B)[:, :n]
+    # the k-th touched vertex is where the running count first reaches k: a
+    # binary search per slot, cheaper on a v5e than jnp.nonzero's
+    # scatter-add of every vertex into the slots (249 against 329 ms at
+    # P 256, Q 128, B 1024)
+    seen = jnp.cumsum(hit, axis=1, dtype=jnp.int32)
+    ids = jnp.minimum(jax.vmap(jnp.searchsorted, (0, None))(
+        seen, jnp.arange(1, slots + 1, dtype=jnp.int32)), n - 1)
+    at = (ids // B, jnp.arange(Q)[:, None], ids % B)
+    return seen[:, -1], ids, p[at], r[at]
+
+
 # ---------------------------------------------------------------------------
 # host-driven engine (Alg. 2 outer loop)
 
 
 @dataclasses.dataclass
 class EngineResult:
-    values: np.ndarray        # [Q, n] distances (minplus) or PPR mass (push)
+    values: np.ndarray        # [Q, n] distances (minplus) or PPR mass (push);
+    #                           SupportRows under support=True
     residual: Optional[np.ndarray]
     edges_processed: np.ndarray  # [Q] float64, exact (host-accumulated)
     stats: VisitStats
@@ -258,7 +288,8 @@ class FPPEngine:
 
     def run(self, sources: np.ndarray, max_visits: int | None = None,
             record_order: bool = False,
-            host_loop: bool = False) -> EngineResult:
+            host_loop: bool = False,
+            support: bool = False) -> EngineResult:
         """Run the engine to completion (or ``max_visits``).
 
         The default path dispatches K-visit *megasteps*: partition selection
@@ -268,6 +299,11 @@ class FPPEngine:
         loop with the numpy :class:`PartitionScheduler`; it is the oracle the
         megastep is tested against (tests/test_megastep.py) and the baseline
         the dispatch microbench compares (benchmarks/bench_dispatch.py).
+
+        ``support=True`` (push mode) delivers ``values`` and ``residual``
+        as :class:`~repro.core.support.SupportRows`: each lane's touched
+        vertices are gathered on the device and only they cross to the
+        host (:meth:`_finalize`).
         """
         if len(sources) != self.num_queries:
             raise ValueError(
@@ -280,8 +316,9 @@ class FPPEngine:
                 x.nbytes for x in jax.tree_util.tree_leaves(state)))
         max_visits = max_visits or 2000 * self.bg.num_parts
         if host_loop:
-            return self._run_host_loop(state, max_visits, record_order)
-        visits = rounds = syncs = reads = traces = 0
+            return self._run_host_loop(state, max_visits, record_order,
+                                       support)
+        visits = rounds = lanes = syncs = reads = traces = 0
         order: list = []
         counts = np.zeros(self.dg.num_parts, dtype=np.int64)
         # edge counts leave the device as an exact (hi, lo) int32 pair per
@@ -313,7 +350,9 @@ class FPPEngine:
             with spans.span(spans.HARVEST, chunk=chunk, reads=harvest_reads):
                 edges += _visit.harvest_edges(ms.eq_hi, ms.eq_lo)
                 counts += np.asarray(ms.visit_counts, dtype=np.int64)
-                rounds += int(ms.rounds)
+                tallies = np.asarray(ms.tallies)
+                rounds += int(tallies[0])
+                lanes += int(tallies[1])
                 if record_order:
                     order.extend(int(x) for x in np.asarray(ms.order)[:v])
             reads += harvest_reads
@@ -327,15 +366,17 @@ class FPPEngine:
             visits=visits, rounds=rounds,
             modeled_bytes=float(counts @ self._visit_bytes),
             host_syncs=syncs, visit_counts=counts, megastep_traces=traces,
-            chunk_reads=reads, relax_width=self.relax_width)
+            chunk_reads=reads, relax_width=self.relax_width,
+            lane_visits=lanes)
         with spans.span(spans.FINALIZE):
-            return self._finalize(state, edges, stats, order)
+            return self._finalize(state, edges, stats, order, support)
 
     def _run_host_loop(self, state: VisitState, max_visits: int,
-                       record_order: bool) -> EngineResult:
+                       record_order: bool,
+                       support: bool = False) -> EngineResult:
         """Legacy per-visit loop: prio/stamp/ops sync to host, numpy argmin,
         one jitted visit per dispatch — O(visits) host synchronizations."""
-        visits = rounds = reads = 0
+        visits = rounds = lanes = reads = 0
         counts = np.zeros(self.dg.num_parts, dtype=np.int64)
         order: list = []
         counter = 0
@@ -350,7 +391,9 @@ class FPPEngine:
                 break
             state, (r, eq) = self._visit(state, jnp.int32(p),
                                          jnp.int32(counter))
-            edges += np.asarray(eq, dtype=np.float64)
+            eq = np.asarray(eq)
+            edges += eq
+            lanes += int(np.count_nonzero(eq))
             counter += 1
             visits += 1
             rounds += int(r)
@@ -361,13 +404,33 @@ class FPPEngine:
         stats = VisitStats(visits=visits, rounds=rounds,
                            modeled_bytes=float(counts @ self._visit_bytes),
                            host_syncs=visits, visit_counts=counts,
-                           chunk_reads=reads, relax_width=self.relax_width)
+                           chunk_reads=reads, relax_width=self.relax_width,
+                           lane_visits=lanes)
         with spans.span(spans.FINALIZE):
-            return self._finalize(state, edges, stats, order)
+            return self._finalize(state, edges, stats, order, support)
 
     def _finalize(self, state: VisitState, edges: np.ndarray,
-                  stats: VisitStats, order: list) -> EngineResult:
+                  stats: VisitStats, order: list,
+                  support: bool = False) -> EngineResult:
         n = self.bg.n
+        if support and self.mode == "push":
+            slots = min(n, SUPPORT_SLOTS)
+            count, idx, p, r = jax.device_get(_touched(
+                state.planes[0], state.planes[1], state.buf, n, slots))
+            if count.max(initial=0) <= slots:
+                held = np.arange(slots) < count[:, None]
+                rows, cols = np.nonzero(held)[0], idx[held]
+                q = self.num_queries
+                return EngineResult(
+                    SupportRows.from_entries(rows, cols, p[held], q, n),
+                    SupportRows.from_entries(rows, cols, r[held], q, n),
+                    edges, stats, order)
+            # a lane touched more vertices than the slots hold: read the
+            # planes whole
+            res = self._finalize(state, edges, stats, order)
+            return dataclasses.replace(
+                res, values=SupportRows.from_dense(res.values),
+                residual=SupportRows.from_dense(res.residual))
         if self.mode != "push":
             dist = state.planes[0]
             vals = np.asarray(dist).transpose(1, 0, 2).reshape(

@@ -108,6 +108,34 @@ def ppr_push(g: CSRGraph, src: int, alpha: float = 0.15,
     return p.astype(np.float32), r.astype(np.float32), edges
 
 
+def sweep_conductance_ref(g: CSRGraph, p: np.ndarray):
+    """Whole-graph sweep cut over one PPR vector, the plain reference of
+    ``applications.sweep_conductance``: it ranks all n vertices and scans
+    every arc.  Returns (sizes, conductances) along the sweep prefix order
+    (deg-normalized, ACL standard)."""
+    deg = g.out_degree().astype(np.float64)
+    support = np.flatnonzero(p > 0)
+    if support.size < 2:
+        return np.array([], dtype=np.int64), np.array([])
+    score = p[support] / np.maximum(deg[support], 1.0)
+    order = support[np.argsort(-score, kind="stable")]
+    rank = np.full(g.n, np.iinfo(np.int64).max, dtype=np.int64)
+    rank[order] = np.arange(order.size)
+    vol = np.cumsum(deg[order])
+    src, dst, _ = g.edges()
+    both = (rank[src] < order.size) & (rank[dst] < order.size)
+    eranks = np.maximum(rank[src[both]], rank[dst[both]])
+    internal = np.bincount(eranks, minlength=order.size).astype(np.float64)
+    cut = vol - np.cumsum(internal)
+    m2 = float(deg.sum())
+    denom = np.minimum(vol, m2 - vol)
+    keep = denom > 0
+    cond = np.full(order.size, np.inf)
+    cond[keep] = cut[keep] / denom[keep]
+    sizes = np.arange(1, order.size + 1)
+    return sizes, cond
+
+
 def connected_components(g: CSRGraph) -> np.ndarray:
     """Union-find component labels; label = min vertex id in the component.
 

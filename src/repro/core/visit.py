@@ -31,6 +31,7 @@ stay exact past float32's 2^24 integer ceiling on paper-scale graphs.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -314,16 +315,39 @@ def state_meta(algebra: VisitAlgebra, planes, buf, deg, counter: int = 0):
     return prio, ops, stamp
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _fresh_dense_state(plane_init, identity, source_value, shape, parts,
+                       lanes, locs):
+    """:func:`init_dense_state` (trash row included, no ``init_ops``) made
+    on the device: only the sources' positions cross from the host."""
+    P, Q, B = shape
+    planes = tuple(jnp.full((P, Q, B), v, jnp.float32) for v in plane_init)
+    buf = jnp.full((P + 1, Q, B), identity, jnp.float32)
+    return planes, buf.at[parts, lanes, locs].set(source_value)
+
+
 def init_engine_state(algebra: VisitAlgebra, dg, sources: np.ndarray,
                       num_queries: Optional[int] = None,
                       init_ops: Optional[np.ndarray] = None) -> VisitState:
-    """Device state for the host-scheduled engine (trash buffer row included)."""
+    """Device state for the host-scheduled engine (trash buffer row included).
+
+    Without ``init_ops`` the planes and buffer are filled on the device,
+    bit for bit :func:`init_dense_state`'s."""
     Q = int(num_queries if num_queries is not None else len(sources))
-    planes_np, buf_np = init_dense_state(
-        algebra, dg.num_parts, Q, dg.block_size, sources, trash_row=True,
-        init_ops=init_ops)
-    planes = tuple(jnp.asarray(x) for x in planes_np)
-    buf = jnp.asarray(buf_np)
+    if init_ops is None:
+        parts, locs = np.divmod(np.asarray(sources, dtype=np.int64),
+                                dg.block_size)
+        planes, buf = _fresh_dense_state(
+            tuple(float(v) for v in algebra.plane_init),
+            float(algebra.identity), float(algebra.source_value),
+            (dg.num_parts, Q, dg.block_size), parts.astype(np.int32),
+            np.arange(parts.size, dtype=np.int32), locs.astype(np.int32))
+    else:
+        planes_np, buf_np = init_dense_state(
+            algebra, dg.num_parts, Q, dg.block_size, sources, trash_row=True,
+            init_ops=init_ops)
+        planes = tuple(jnp.asarray(x) for x in planes_np)
+        buf = jnp.asarray(buf_np)
     prio, ops, stamp = state_meta(algebra, planes, buf, dg.deg)
     return VisitState(planes, buf, prio, ops, stamp)
 
@@ -547,7 +571,9 @@ def device_select(policy: str, prio: jax.Array, stamp: jax.Array,
 class MegastepStats(NamedTuple):
     """Per-chunk device accumulators, harvested once per host dispatch."""
     visits: jax.Array        # i32 scalar: visits executed this chunk (<= K)
-    rounds: jax.Array        # i32 scalar: total relaxation rounds
+    tallies: jax.Array       # [2] i32: total relaxation rounds, and lane
+    #                          visits (query lanes that processed an edge,
+    #                          summed over the visits); one read for both
     eq_hi: jax.Array         # [Q] i32: per-query edge count, high lane
     eq_lo: jax.Array         # [Q] i32: low lane (< 2**EDGE_SHIFT)
     visit_counts: jax.Array  # [P] i32: visits per partition (traffic model)
@@ -659,7 +685,7 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
                                    jnp.any(jnp.isfinite(meta(st)[0])))
 
         def body(c):
-            st, k, rounds, hi, lo, counts, order, key = c
+            st, k, tallies, hi, lo, counts, order, key = c
             if policy == "random":          # trace-time: only the random
                 key, sub = jax.random.split(key)  # policy consumes entropy
             else:
@@ -674,21 +700,24 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
             lo = lo - (spill << EDGE_SHIFT)
             counts = counts.at[p].add(1)
             order = order.at[k].set(p.astype(jnp.int32))
-            return st, k + 1, rounds + r, hi, lo, counts, order, key
+            # once per visit, from the visit's own per-query edge counts
+            tallies = tallies + jnp.stack(
+                [r, jnp.sum(eq > 0, dtype=jnp.int32)])
+            return st, k + 1, tallies, hi, lo, counts, order, key
 
         Q = state.buf.shape[1]
-        init = (enter(state), jnp.int32(0), jnp.int32(0),
+        init = (enter(state), jnp.int32(0), jnp.zeros(2, jnp.int32),
                 jnp.zeros(Q, jnp.int32), jnp.zeros(Q, jnp.int32),
                 jnp.zeros(P, jnp.int32), jnp.full((K,), -1, jnp.int32), key)
-        st, k, rounds, hi, lo, counts, order, key = jax.lax.while_loop(
+        st, k, tallies, hi, lo, counts, order, key = jax.lax.while_loop(
             cond, body, init)
         st = leave(st)
         with jax.named_scope("harvest_mask"):
             lane_pending = (jnp.any(
                 algebra.pending(st.buf[:P], st.planes, g.deg), axis=(0, 2))
                 if harvest_mask else jnp.zeros((0,), dtype=bool))
-        return st, MegastepStats(visits=k, rounds=rounds, eq_hi=hi, eq_lo=lo,
-                                 visit_counts=counts, order=order,
+        return st, MegastepStats(visits=k, tallies=tallies, eq_hi=hi,
+                                 eq_lo=lo, visit_counts=counts, order=order,
                                  lane_pending=lane_pending, key=key)
 
     bound = GraphBound(megastep, dg)

@@ -28,6 +28,7 @@ from repro.core.baselines import (global_minplus, global_push,
 from repro.core.engine import FPPEngine
 from repro.core.graph import BlockGraph
 from repro.core.oracles import decode_kreach
+from repro.core.support import SupportRows
 from repro.core.visit import cc_label_plane
 from repro.core.yielding import YieldConfig
 
@@ -49,6 +50,9 @@ class BackendResult:
 
 
 def _normalize(values, residual, edges, stats) -> BackendResult:
+    if isinstance(values, SupportRows):
+        return BackendResult(values, residual,
+                             np.asarray(edges, dtype=np.float64), stats)
     return BackendResult(
         values=np.ascontiguousarray(np.asarray(values, dtype=np.float32)),
         residual=(None if residual is None
@@ -105,7 +109,8 @@ def run_query(backend: str, kind: str, bg: BlockGraph, sources: np.ndarray,
               fused: bool = False,
               frontier_mode: str = "dense",
               k: int = 8, hop_stride: float = 1.0,
-              length: int = 32, seed: int = 0) -> BackendResult:
+              length: int = 32, seed: int = 0,
+              support: bool = False) -> BackendResult:
     """Run one query batch (sources in reordered ids) on one backend.
 
     ``fused=True`` (engine backend only) swaps each visit body for the
@@ -124,6 +129,8 @@ def run_query(backend: str, kind: str, bg: BlockGraph, sources: np.ndarray,
     across all three backends (see core/randomwalk.py's tape contract).
     Raw ``cc`` values are reordered-rep labels — callers canonicalize with
     :func:`canonicalize_cc` after mapping back to original ids.
+    ``support=True`` has the engine deliver ``ppr`` planes as
+    :class:`~repro.core.support.SupportRows`; other backends ignore it.
     """
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; one of {BACKENDS}")
@@ -158,7 +165,7 @@ def run_query(backend: str, kind: str, bg: BlockGraph, sources: np.ndarray,
                             frontier_mode=frontier_mode,
                             hop_budget=k, hop_stride=hop_stride)
             spans.note(sp, relax_width=eng.relax_width)
-        res = eng.run(sources, max_visits=max_visits)
+        res = eng.run(sources, max_visits=max_visits, support=support)
         return _normalize(res.values, res.residual, res.edges_processed, {
             "visits": res.stats.visits, "rounds": res.stats.rounds,
             "modeled_bytes": res.stats.modeled_bytes,
@@ -166,7 +173,8 @@ def run_query(backend: str, kind: str, bg: BlockGraph, sources: np.ndarray,
             "visit_counts": res.stats.visit_counts,
             "megastep_traces": res.stats.megastep_traces,
             "chunk_reads": res.stats.chunk_reads,
-            "relax_width": res.stats.relax_width})
+            "relax_width": res.stats.relax_width,
+            "lane_visits": res.stats.lane_visits})
 
     if backend == "baselines":
         if kind == "ppr":

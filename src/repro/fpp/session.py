@@ -33,6 +33,7 @@ import numpy as np
 from repro import spans
 from repro.core.graph import BlockGraph, CSRGraph
 from repro.core.partition import partition
+from repro.core.support import SupportRows
 from repro.core.yielding import YieldConfig
 from repro.fpp import backends as _backends
 from repro.fpp import planner as _planner
@@ -42,7 +43,8 @@ from repro.fpp.planner import MemoryModel, Plan
 #: the counts the ``fpp.run`` span carries at its end: (arg, stats key)
 _RUN_COUNTS = (("visits", "visits"), ("chunks", "host_syncs"),
                ("chunk_reads", "chunk_reads"),
-               ("megastep_traces", "megastep_traces"))
+               ("megastep_traces", "megastep_traces"),
+               ("lane_visits", "lane_visits"))
 
 
 @dataclasses.dataclass
@@ -50,7 +52,8 @@ class SessionResult:
     """Backend-independent result, in the ORIGINAL vertex id space."""
     kind: str
     backend: str
-    values: np.ndarray                # [Q, n] float32
+    values: np.ndarray                # [Q, n] float32; SupportRows under
+    #                                   delivery="support"
     residual: Optional[np.ndarray]    # [Q, n] float32 (ppr) or None
     edges_processed: np.ndarray       # [Q] float64
     stats: dict
@@ -174,7 +177,7 @@ class FPPSession:
             fused: Optional[bool] = None,
             frontier_mode: str = "dense",
             k: int = 8, length: int = 32,
-            seed: int = 0) -> SessionResult:
+            seed: int = 0, delivery: str = "dense") -> SessionResult:
         """Execute one query batch.  Sources and values use original ids.
 
         ``fused`` defaults to the plan's setting (``plan(fused=True)``);
@@ -189,8 +192,20 @@ class FPPSession:
         budget; residual = hop counts), ``rw`` takes ``length``/``seed``
         (values = occupancy counts; fused is not applicable and is
         ignored — the walker loop has no megastep to fuse).
+
+        ``delivery="support"`` (``ppr`` only) returns ``values`` and
+        ``residual`` as :class:`~repro.core.support.SupportRows`, each
+        lane's touched vertices alone: the engine gathers them on the
+        device, so a batch moves kilobytes to the host, not two dense
+        ``[Q, n]`` planes.
         """
         from repro.core.queries import WEIGHT_VARIANTS
+        if delivery not in ("dense", "support"):
+            raise ValueError(f"unknown delivery {delivery!r}; dense or "
+                             f"support")
+        if delivery == "support" and kind != "ppr":
+            raise ValueError(f"delivery='support' is for ppr, not {kind!r}")
+        support = delivery == "support"
         sources = np.asarray(sources)
         p = self.current_plan
         bk = backend or p.backend
@@ -222,13 +237,23 @@ class FPPSession:
                 fused=bool(fused) and kind != "rw",
                 frontier_mode=frontier_mode, k=k,
                 hop_stride=(self.kreach_stride if kind == "kreach" else 1.0),
-                length=length, seed=seed)
+                length=length, seed=seed, support=support)
             with spans.span(spans.FINALIZE):
-                values = out.values[:, perm]  # back to original vertex ids
+                # back to original vertex ids, as row-major planes:
+                # ``x[:, perm]`` would return them column-major, a strided
+                # row for every later per-query scan (the NCP sweep's)
+                if support:
+                    values, residual = (
+                        (x if isinstance(x, SupportRows)
+                         else SupportRows.from_dense(x)).relabel(
+                             np.argsort(perm))
+                        for x in (out.values, out.residual))
+                else:
+                    values = np.take(out.values, perm, axis=1)
+                    residual = (None if out.residual is None
+                                else np.take(out.residual, perm, axis=1))
                 if kind == "cc":
                     values = _backends.canonicalize_cc(values)
-                residual = (None if out.residual is None
-                            else out.residual[:, perm])
             spans.note(sp, **{arg: out.stats[key] for arg, key in _RUN_COUNTS
                               if key in out.stats})
         return SessionResult(kind=kind, backend=bk,
@@ -298,11 +323,27 @@ class FPPSession:
 
     def ncp(self, seeds: np.ndarray, *, alpha: float = 0.15,
             eps: float = 1e-4, max_size: Optional[int] = None, **run_kw):
-        """Network community profile from a fleet of PPRs."""
-        from repro.core.applications import ncp_profile
+        """Network community profile from a fleet of PPRs: ``(profile,
+        result)``, where ``profile`` is the min conductance per log2
+        cluster-size bin over every seed's sweep cut.
+
+        The sweep reads only each seed's support ``{v : p(v) > 0}`` and its
+        CSR rows (``applications.ncp_profile``); its conductances equal the
+        whole-graph sweep's (``oracles.sweep_conductance_ref``) bit for bit.
+        With ``delivery="support"`` in ``run_kw`` the supports come from
+        the device as they are (``run``) and no dense plane is made; else
+        one scan of the batch's values finds them.  The sweep runs under an
+        ``fpp.sweep`` span noted with ``seeds`` and the summed ``support``;
+        ``result.stats["lane_visits"]`` counts the query lanes the push
+        visits moved."""
+        from repro.core.applications import ncp_profile, support_of
         res = self.run("ppr", seeds, alpha=alpha, eps=eps, **run_kw)
-        return ncp_profile(self.graph, res.values,
-                           max_size=max_size), res
+        with spans.span(spans.SWEEP, seeds=len(res.sources)) as sp:
+            support = support_of(res.values)
+            profile = ncp_profile(self.graph, res.values, max_size=max_size,
+                                  support=support)
+            spans.note(sp, support=int(support[0].size))
+        return profile, res
 
     def random_walks(self, sources: np.ndarray, length: int = 32, *,
                      seed: int = 0, block_size: Optional[int] = None,

@@ -190,6 +190,8 @@ class StreamingExecutor:
         self.visits = 0
         self.modeled_bytes = 0.0
         self.host_syncs = 0
+        # query lanes that processed an edge, summed over the visits
+        self.lane_visits = 0
         self._key = jax.random.PRNGKey(seed)
         self._lane_pending: Optional[np.ndarray] = None  # set by _chunk
         self._drained = False                            # set by _chunk
@@ -389,7 +391,9 @@ class StreamingExecutor:
             return bool(self.queue) or self.active > 0
         self.state, (_, eq) = self.engine._visit(self.state, jnp.int32(p),
                                                  jnp.int32(self.visits))
-        self._edges += np.asarray(eq, dtype=np.float64)
+        eq = np.asarray(eq)
+        self._edges += eq
+        self.lane_visits += int(np.count_nonzero(eq))
         self.visits += 1
         self.modeled_bytes += float(self.engine._visit_bytes[p])
         if self.visits % self.harvest_every == 0:
@@ -417,7 +421,9 @@ class StreamingExecutor:
             # (megastep recomputes it from the unchanged input state); a
             # chunk that stops below its limit proves the device is
             # drained — no confirmation dispatch needed
-            self._lane_pending = np.asarray(ms.lane_pending)
+            # both small planes in one round trip
+            self._lane_pending, tallies = jax.device_get(
+                (ms.lane_pending, ms.tallies))
             self._drained = v < limit
             if v == 0:
                 return 0
@@ -425,6 +431,7 @@ class StreamingExecutor:
             self._key = ms.key
             self._edges += _visit.harvest_edges(ms.eq_hi, ms.eq_lo)
             counts = np.asarray(ms.visit_counts, dtype=np.int64)
+        self.lane_visits += int(tallies[1])
         self.modeled_bytes += float(counts @ self.engine._visit_bytes)
         self.visits += v
         return v

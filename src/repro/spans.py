@@ -32,6 +32,8 @@ SYNC = "fpp.sync"
 HARVEST = "fpp.harvest"
 #: device state to host result planes in original vertex ids
 FINALIZE = "fpp.finalize"
+#: ``FPPSession.ncp``'s sweep cuts over the batch's PPR vectors
+SWEEP = "fpp.sweep"
 #: ``GraphServer.submit``, ``_admit`` (per request) and delivery (per
 #: response); each carries the request's ``rid``
 SUBMIT = "serve.submit"

@@ -7,6 +7,7 @@ from repro.core.baselines import global_minplus, global_push
 from repro.core.partition import edge_cut_fraction, partition
 from repro.core.queries import prepare, run_rw, run_sssp
 from repro.core.scheduler import PartitionScheduler
+from repro.core.support import SupportRows
 from repro.core.yielding import YieldConfig
 from repro.graphs.generators import build_suite, grid2d, rmat
 
@@ -162,3 +163,134 @@ def test_partition_bfs_beats_random_cut_on_grid():
     bg_bfs, _ = partition(g, 32, method="bfs")
     bg_rnd, _ = partition(g, 32, method="random")
     assert edge_cut_fraction(bg_bfs) < edge_cut_fraction(bg_rnd)
+
+
+def _ppr_like(n, rng, k, tied):
+    """A PPR-shaped vector on ``k`` random vertices; ``tied`` draws from a
+    few scores so that -p/deg ties and the id order breaks them."""
+    p = np.zeros(n, np.float32)
+    at = rng.choice(n, k, replace=False)
+    p[at] = rng.choice([0.5, 0.25, 0.125], k) if tied else rng.random(k)
+    return p
+
+
+GRAPHS = {"rmat": lambda: rmat(8, 6, seed=2),
+          "lattice": lambda: grid2d(13, 11, seed=3)}
+
+
+@pytest.mark.parametrize("support", ["empty", "single", "pair", "small",
+                                     "half", "whole"])
+@pytest.mark.parametrize("tied", [False, True], ids=["distinct", "tied"])
+@pytest.mark.parametrize("graph", sorted(GRAPHS))
+def test_sweep_conductance_is_the_whole_graph_reference_bit_for_bit(
+        graph, tied, support):
+    g = GRAPHS[graph]()
+    k = {"empty": 0, "single": 1, "pair": 2, "small": 17, "half": g.n // 2,
+         "whole": g.n}[support]
+    rng = np.random.default_rng([k, int(tied)])
+    for _ in range(3):
+        p = _ppr_like(g.n, rng, k, tied)
+        sizes, cond = apps.sweep_conductance(g, p)
+        want_sizes, want_cond = oracles.sweep_conductance_ref(g, p)
+        assert sizes.dtype == want_sizes.dtype
+        np.testing.assert_array_equal(sizes, want_sizes)
+        np.testing.assert_array_equal(cond, want_cond)
+        assert sizes.size == (k if k >= 2 else 0)
+
+
+@pytest.mark.parametrize("graph", sorted(GRAPHS))
+def test_ncp_profile_is_the_binwise_minimum_of_the_reference_sweeps(graph):
+    g = GRAPHS[graph]()
+    rng = np.random.default_rng(7)
+    pvals = np.stack([_ppr_like(g.n, rng, k, tied)
+                      for k in (0, 1, 2, 5, 40, g.n // 3, 0, g.n)
+                      for tied in (False, True)])
+    nbins = int(np.ceil(np.log2(g.n))) + 1
+    want = np.full(nbins, np.inf)
+    for p in pvals:
+        sizes, cond = oracles.sweep_conductance_ref(g, p)
+        if sizes.size:
+            np.minimum.at(want, np.minimum(np.log2(sizes).astype(np.int64),
+                                           nbins - 1), cond)
+    np.testing.assert_array_equal(apps.ncp_profile(g, pvals), want)
+    support = apps.support_of(pvals)
+    assert support[0].size == np.count_nonzero(pvals > 0)
+    np.testing.assert_array_equal(
+        apps.ncp_profile(g, pvals, support=support), want)
+    # the same vectors delivered as their supports
+    np.testing.assert_array_equal(
+        apps.ncp_profile(g, SupportRows.from_dense(pvals)), want)
+
+
+def test_session_ncp_meets_the_acl_bound_and_sweeps_exactly():
+    """``FPPSession.ncp`` on a small lattice: every PPR vector within the
+    ACL bound of the exact PageRank (0 <= ppr - p < eps * deg on this
+    symmetric graph) and of the sequential push oracle, and a profile
+    equal to the whole-graph reference sweep of those vectors."""
+    from repro.fpp import FPPSession
+    g = grid2d(16, 16, seed=4)
+    seeds = np.array([0, 37, 130, 255])
+    alpha, eps = 0.15, 1e-4
+    sess = FPPSession(g).plan(num_queries=len(seeds), block_size=32)
+    profile, res = sess.ncp(seeds, alpha=alpha, eps=eps)
+    # row-major planes: each seed's row is one contiguous scan
+    assert res.values.flags.c_contiguous and res.residual.flags.c_contiguous
+    deg = np.maximum(g.out_degree(), 1).astype(np.float64)
+    src, dst, _ = g.edges()
+    walk = np.zeros((g.n, g.n))
+    np.add.at(walk, (dst, src), (1 - alpha) / deg[src])
+    exact = alpha * np.linalg.solve(np.eye(g.n) - walk,
+                                    np.eye(g.n)[:, seeds]).T
+    gap = (exact - res.values) / (eps * deg)
+    assert gap.max() < 1.0 and gap.min() > -1e-3
+    for qi, s in enumerate(seeds):
+        want_p, _, _ = oracles.ppr_push(g, int(s), alpha=alpha, eps=eps)
+        assert (np.abs(res.values[qi] - want_p) / deg).max() <= 2 * eps
+    nbins = int(np.ceil(np.log2(g.n))) + 1
+    want = np.full(nbins, np.inf)
+    for p in res.values:
+        sizes, cond = oracles.sweep_conductance_ref(g, p)
+        np.minimum.at(want, np.minimum(np.log2(sizes).astype(np.int64),
+                                       nbins - 1), cond)
+    assert np.isfinite(want).sum() >= 4
+    np.testing.assert_array_equal(profile, want)
+    assert 0 < res.stats["lane_visits"] <= res.stats["visits"] * len(seeds)
+
+
+@pytest.mark.parametrize("route", ["device", "overflow", "baselines"])
+def test_support_delivery_is_the_dense_planes_bit_for_bit(route,
+                                                          monkeypatch):
+    """``delivery="support"`` hands back the dense run's PPR mass and
+    residual on their supports: gathered on the device, read whole when a
+    lane outgrows the slots, or taken from another backend's planes; and
+    ``sess.ncp`` sweeps it to the same profile."""
+    from repro.core import engine
+    from repro.fpp import FPPSession
+    g = grid2d(16, 16, seed=4)
+    seeds = np.array([0, 37, 130, 255])
+    sess = FPPSession(g).plan(num_queries=len(seeds), block_size=32)
+    kw = {"backend": "baselines"} if route == "baselines" else {}
+    if route == "overflow":
+        monkeypatch.setattr(engine, "SUPPORT_SLOTS", 8)
+    want_profile, want = sess.ncp(seeds, **kw)
+    profile, got = sess.ncp(seeds, delivery="support", **kw)
+    for plane, dense in ((got.values, want.values),
+                         (got.residual, want.residual)):
+        assert isinstance(plane, SupportRows) and plane.shape == dense.shape
+        assert np.all(np.diff(plane.indices)[np.diff(plane.rows()) == 0] > 0)
+        np.testing.assert_array_equal(plane.dense(), dense)
+        for qi in range(len(seeds)):
+            np.testing.assert_array_equal(plane.row(qi), dense[qi])
+    assert got.values.data.size == np.count_nonzero(want.values)
+    np.testing.assert_array_equal(profile, want_profile)
+    assert got.stats.get("visits") == want.stats.get("visits")
+
+
+def test_support_delivery_is_for_ppr_only():
+    from repro.fpp import FPPSession
+    sess = FPPSession(grid2d(6, 6, seed=1)).plan(num_queries=2,
+                                                 block_size=16)
+    with pytest.raises(ValueError, match="for ppr"):
+        sess.run("sssp", np.array([0, 5]), delivery="support")
+    with pytest.raises(ValueError, match="unknown delivery"):
+        sess.run("ppr", np.array([0, 5]), delivery="sparse")

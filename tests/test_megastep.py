@@ -109,6 +109,50 @@ def test_megastep_sync_count_is_o_visits_over_k(mode):
         assert host.stats.host_syncs == host.stats.visits
 
 
+@pytest.mark.parametrize("kind", ["sssp", "ppr"])
+def test_lane_visits_equal_the_host_loops_count(kind):
+    """``lane_visits`` -- the query lanes that processed an edge, summed
+    over the visits -- rides the megastep's tallies beside the rounds and
+    equals the count the host loop takes from each visit's ``eq``."""
+    if kind == "sssp":
+        _, bg, _, srcs = _minplus_setup()
+        eng = FPPEngine(bg, mode="minplus", num_queries=len(srcs),
+                        k_visits=8)
+    else:
+        _, bg, _, _, srcs = _push_setup()
+        eng = FPPEngine(bg, mode="push", num_queries=len(srcs), eps=1e-4,
+                        k_visits=8)
+    host = eng.run(srcs, host_loop=True)
+    mega = eng.run(srcs)
+    assert mega.stats.visits == host.stats.visits
+    assert mega.stats.rounds == host.stats.rounds
+    assert mega.stats.lane_visits == host.stats.lane_visits
+    assert 0 < host.stats.lane_visits <= host.stats.visits * len(srcs)
+    # one harvest read carries both tallies: the chunk's reads stay five
+    assert mega.stats.chunk_reads == 5 * mega.stats.host_syncs
+
+
+@pytest.mark.parametrize("kind", ["sssp", "ppr"])
+def test_streaming_counts_the_lane_visits_of_the_one_shot_run(kind):
+    """A stream that holds the whole batch from the start visits as the
+    one-shot run does, so its chunked and stepped paths both count the
+    run's lane visits."""
+    g = grid2d(12, 12, seed=6)
+    srcs = np.array([0, 40, 80, 120, 143, 7])
+    sess = FPPSession(g).plan(num_queries=len(srcs), block_size=32)
+    one = sess.run(kind, srcs)
+    chunked = sess.stream(kind, capacity=len(srcs), k_visits=16)
+    chunked.submit(srcs)
+    chunked.run()
+    stepped = sess.stream(kind, capacity=len(srcs))
+    stepped.submit(srcs)
+    while stepped.step():
+        pass
+    assert chunked.visits == stepped.visits == one.stats["visits"]
+    assert (chunked.lane_visits == stepped.lane_visits
+            == one.stats["lane_visits"] > 0)
+
+
 def test_megastep_respects_max_visits_exactly():
     """The dynamic ``limit`` operand caps a chunk mid-K, so max_visits keeps
     per-visit granularity without recompiling."""
@@ -225,3 +269,23 @@ def test_streaming_step_path_matches_chunked_pump():
         np.testing.assert_array_equal(out_pump[qid], out_step[qid])
     # the whole point of the chunked path: far fewer host consultations
     assert chunked.host_syncs < stepped.visits
+
+
+@pytest.mark.parametrize("mode", ["minplus", "push"])
+@pytest.mark.parametrize("lanes", [0, 3, 8])
+def test_device_made_state_is_the_host_made_state_bit_for_bit(mode, lanes):
+    """``init_engine_state`` fills the planes and the buffer on the device;
+    they equal ``init_dense_state``'s host arrays bit for bit, sources on
+    some lanes (streaming admission) or all of them (a run)."""
+    g = grid2d(12, 12, seed=2)
+    bg, perm = partition(g, 32)
+    eng = FPPEngine(bg, mode=mode, num_queries=8)
+    sources = perm[np.random.default_rng(lanes).choice(g.n, lanes,
+                                                       replace=False)]
+    state = V.init_engine_state(eng.algebra, eng.dg, sources, num_queries=8)
+    planes, buf = V.init_dense_state(eng.algebra, bg.num_parts, 8,
+                                     bg.block_size, sources)
+    for got, want in zip((*state.planes, state.buf), (*planes, buf)):
+        got = np.asarray(got)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()

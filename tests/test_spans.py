@@ -132,6 +132,19 @@ def test_init_state_span_carries_the_state_bytes(traced):
     assert init[4]["bytes"] == 4 * (P * Q * B + (P + 1) * Q * B + 3 * P)
 
 
+def test_ncp_sweep_span_follows_the_run_with_its_counts(session, tmp_path):
+    seeds = np.array([0, 100, 300, 575])
+    with jax.profiler.trace(str(tmp_path)):
+        profile, res = session.ncp(seeds)
+    found = recorded(str(tmp_path))
+    sweep, = [f for f in found if f[0] == spans.SWEEP]
+    root, = [f for f in found if f[0] == spans.RUN]
+    assert parents(found, sweep) == [] and root[2] <= sweep[1]
+    assert sweep[4]["seeds"] == 4
+    assert sweep[4]["support"] == np.count_nonzero(res.values > 0)
+    assert root[4]["lane_visits"] == res.stats["lane_visits"] > 0
+
+
 def test_host_loop_counts_visits_per_partition(session):
     bg, perm = session.prepared()
     src = perm[np.array([0, 100, 300, 575])]

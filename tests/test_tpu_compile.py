@@ -202,3 +202,25 @@ def test_distributed_program_compiles_for_four_chips(topo, kind):
     assert "all-to-all" in compiled.as_text()
     slab = P * 7 * B * B * 4
     assert compiled.memory_analysis().argument_size_in_bytes < slab // 3
+
+
+def test_support_delivery_and_state_init_compile_at_ncp_scale(shape):
+    """The push run's device-side state fill and its support gather, at the
+    NCP cell's shape (P 256, Q 128, B 1024), compile for one v5e; the
+    gather's temporaries (the residual with its buffer folded in, the
+    lane-major hit mask and its scan, 0.54 GB at this shape) stay under
+    1 GiB beside the 3.21 GB block store and the 0.40 GB state."""
+    from repro.core import engine
+    from repro.core import visit as V
+    p, q, b = 256, 128, 1024
+    n = p * b
+    lanes = shape((q,), jnp.int32)
+    fill = V._fresh_dense_state.lower((0.0, 0.0), 0.0, 1.0, (p, q, b),
+                                      lanes, lanes, lanes).compile()
+    plane = shape((p, q, b))
+    gather = engine._touched.lower(plane, plane, shape((p + 1, q, b)), n,
+                                   engine.SUPPORT_SLOTS).compile()
+    for compiled in (fill, gather):
+        mem = compiled.memory_analysis()
+        if mem is not None:
+            assert mem.temp_size_in_bytes < 2 ** 30
