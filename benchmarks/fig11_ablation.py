@@ -13,8 +13,6 @@ Ligra baseline.  Here:
 """
 from __future__ import annotations
 
-import numpy as np
-
 from benchmarks.common import rnd, sources_for, timed
 from repro.core.baselines import global_minplus
 from repro.core.queries import prepare, run_sssp
@@ -30,8 +28,6 @@ def run(quick: bool = True):
         g = build_suite(gname)
         srcs = sources_for(g, nq, seed=6)
         bg, perm = prepare(g, 256)
-        wmax = float(np.nanmax(np.where(np.isfinite(bg.blocks),
-                                        bg.blocks, np.nan)))
         base, bsecs = timed(global_minplus, bg, perm[srcs])
         variants = [
             ("+buffer(fifo,noyield)",
@@ -40,8 +36,8 @@ def run(quick: bool = True):
              dict(schedule="priority", yield_config=NO_YIELD)),
             ("+yield(full)",
              dict(schedule="priority",
-                  yield_config=YieldConfig(mu_factor=2.0,
-                                           delta=default_delta(wmax)))),
+                  yield_config=YieldConfig(
+                      mu_factor=2.0, delta=default_delta(bg.weight_max)))),
         ]
         rows.append({"graph": gname, "variant": "baseline(global)",
                      "runtime_s": rnd(bsecs),

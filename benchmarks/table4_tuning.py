@@ -11,8 +11,6 @@ unit ``repro.fpp.planner.measure_run`` — the same code path the planner's
 """
 from __future__ import annotations
 
-import numpy as np
-
 from benchmarks.common import rnd, sources_for
 from repro.core.yielding import YieldConfig, default_delta
 from repro.fpp import FPPSession
@@ -33,9 +31,7 @@ def run(quick: bool = True):
     srcs = sources_for(g, nq, seed=7)
     sess = FPPSession(g).plan(num_queries=nq, block_size=256, method="bfs")
     bg, _ = sess.prepared()
-    wmax = float(np.nanmax(np.where(np.isfinite(bg.blocks), bg.blocks,
-                                    np.nan)))
-    delta = default_delta(wmax)
+    delta = default_delta(bg.weight_max)
     rows = []
     # A: policies (yielding enabled, Δ)
     for policy in ("random", "max_ops", "fifo", "priority"):

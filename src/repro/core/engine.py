@@ -98,6 +98,18 @@ def _staged(bg: BlockGraph) -> dict:
         return arrays
 
 
+def program_table(bg: BlockGraph) -> _visit.ProgramTable:
+    """The megastep programs traced over ``bg``, beside its staged arrays:
+    made once per BlockGraph and freed with it, never shared across
+    graphs, so an engine with the constants of an earlier one runs the
+    program that one traced (``core/visit.make_megastep``)."""
+    with _stage_lock:
+        table = bg.__dict__.get("_megastep_programs")
+        if table is None:
+            table = bg.__dict__["_megastep_programs"] = _visit.ProgramTable()
+        return table
+
+
 @functools.partial(jax.tree_util.register_dataclass,
                    data_fields=list(_STAGED) + ["edge_budget"],
                    meta_fields=["num_parts", "block_size", "dmax",
@@ -260,10 +272,13 @@ class FPPEngine:
         self._visit = _visit.make_visit(self.dg, self.algebra, max_rounds)
         # the hot loop: K visits per host dispatch, scheduler on device;
         # fused=True swaps the visit body for the fused Pallas kernel
+        self.programs = program_table(bg)
         self._megastep = _visit.make_megastep(
             self.dg, self.algebra, max_rounds, policy=schedule,
             K=self.k_visits, fused=self.fused,
-            frontier_mode=self.frontier_mode)
+            frontier_mode=self.frontier_mode, programs=self.programs)
+        # a wrapped megastep may not say
+        self.program_reused = bool(getattr(self._megastep, "reused", False))
         # the fused kernel contracts dense tiles whatever the algebra has
         self.relax_width = (bg.block_size if self.fused
                             else _visit.relax_width(self.dg, self.algebra))

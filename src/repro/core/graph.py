@@ -98,6 +98,9 @@ class BlockGraph:
                                       (padding: row 0)
     ell_w       float32 [nblk, B, W]  their weights, the same f32 values as
                                       ``blocks`` (padding: +inf)
+    weight_max  float                 the largest finite value of ``blocks``
+                                      (NaN when there is none): the
+                                      Δ-window's scale
 
     ``W`` is the widest destination column over all blocks.  The view is
     built only when ``W <= B * ELL_MAX_WIDTH_SHARE``; otherwise (hub-heavy
@@ -120,6 +123,7 @@ class BlockGraph:
     num_parts: int
     n: int                 # real vertex count (pre-padding)
     m: int
+    weight_max: float
 
     @property
     def n_padded(self) -> int:
@@ -153,6 +157,11 @@ class BlockGraph:
         # duplicate edges (a CSR built with dedup=False) keep their minimum
         flat = blocks.reshape(nblk, B * B)
         np.minimum.at(flat, (bk, ul * B + vl), w)
+        # every finite block value is some edge's min-folded weight, so the
+        # edges' own cells hold the store's maximum: O(m), not a scan of it
+        folded = flat[bk, ul * B + vl]
+        folded = folded[np.isfinite(folded)]
+        weight_max = float(folded.max()) if folded.size else float("nan")
         ell_src, ell_w = _pull_ell(nblk, B, bk, ul, vl, w)
         diag_blk = np.searchsorted(uniq, diag_pairs).astype(np.int32)
         # neighbor lists excluding the diagonal
@@ -177,7 +186,8 @@ class BlockGraph:
                           nbr_blk=nbr_blk, nbr_part=nbr_part, diag_blk=diag_blk,
                           row_nnz=row_nnz, deg=deg, vmask=vmask,
                           ell_src=ell_src, ell_w=ell_w,
-                          block_size=B, num_parts=P, n=g.n, m=g.m)
+                          block_size=B, num_parts=P, n=g.n, m=g.m,
+                          weight_max=weight_max)
 
     def nbytes(self) -> int:
         return sum(a.nbytes for a in

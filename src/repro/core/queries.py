@@ -63,9 +63,7 @@ def run_sssp(bg: BlockGraph, sources: np.ndarray,
              yield_config: Optional[YieldConfig] = None,
              schedule: str = "priority", use_pallas: bool = False,
              **run_kwargs) -> EngineResult:
-    yc = yield_config or YieldConfig(
-        delta=default_delta(float(np.nanmax(np.where(
-            np.isfinite(bg.blocks), bg.blocks, np.nan)))))
+    yc = yield_config or YieldConfig(delta=default_delta(bg.weight_max))
     eng = FPPEngine(bg, mode="minplus", num_queries=len(sources),
                     yield_config=yc, schedule=schedule, use_pallas=use_pallas)
     return eng.run(np.asarray(sources), **run_kwargs)

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -496,13 +497,22 @@ class GraphBound:
     convention.
 
     ``traces`` counts how often the program's Python body ran, that is,
-    was traced (a program that counts its traces bumps it from its body).
+    was traced; a program that counts its traces (the megastep) bumps its
+    :class:`_Traced` record, which every bound over it shares.
+    ``reused`` is True where a :class:`ProgramTable` already held the
+    program.
     """
 
-    def __init__(self, fn, graph):
+    def __init__(self, fn, graph, program: Optional["_Traced"] = None,
+                 reused: bool = False):
         self.fn = fn
         self.graph = graph
-        self.traces = 0
+        self.program = program
+        self.reused = reused
+
+    @property
+    def traces(self) -> int:
+        return self.program.traces if self.program is not None else 0
 
     def __call__(self, *args):
         return self.fn(self.graph, *args)
@@ -583,10 +593,54 @@ class MegastepStats(NamedTuple):
     key: jax.Array           # threefry key to carry into the next chunk
 
 
+class _Traced:
+    """A jitted program and how often its Python body ran (was traced)."""
+
+    def __init__(self):
+        self.fn = None
+        self.traces = 0
+
+
+class ProgramTable:
+    """The jitted megasteps built over one partitioned graph, keyed by
+    every constant their traces close over (:func:`make_megastep`).
+
+    Each engine over one ``BlockGraph`` — a session's runs, each serving
+    pool's executor, the compile cache's warm builds — takes its program
+    from the graph's table (``core/engine.program_table``), so a program is
+    traced once per graph and constants, not once per engine; the shapes
+    are ``jax.jit``'s own cache.  Get-or-build holds a lock: the serving
+    pools warm their programs on threads of their own.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._programs: dict = {}
+
+    def get(self, key, build: Callable[[], _Traced]) -> Tuple[_Traced, bool]:
+        """``(program, reused)``: the program under ``key``, built by
+        ``build()`` and kept when the table has none."""
+        with self._lock:
+            program = self._programs.get(key)
+            if program is not None:
+                return program, True
+            program = self._programs[key] = build()
+            return program, False
+
+
+def _algebra_key(algebra: VisitAlgebra) -> tuple:
+    """What an algebra's operators are made from: two algebras with equal
+    keys compute the same bits (``minplus_algebra``/``push_algebra`` are
+    closures over their parameters and their dense relax or spread)."""
+    return (algebra.name, algebra.params, algebra.contrib,
+            algebra.ell is not None)
+
+
 def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
                   policy: str = "priority", K: int = 64,
                   harvest_mask: bool = False, fused: bool = False,
-                  frontier_mode: str = "dense") -> Callable:
+                  frontier_mode: str = "dense",
+                  programs: Optional[ProgramTable] = None) -> GraphBound:
     """Device-resident scheduling loop: up to K visits per host dispatch.
 
     Wraps the visit body in a ``lax.while_loop`` whose scheduler decision is
@@ -621,6 +675,13 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
     ``frontier_mode="sparse"`` (minplus only) makes the in-kernel relax
     skip all-inf source chunks — identical bits, less work on the thin
     late-round frontiers (DESIGN.md §2.4).
+
+    Each call returns a new :class:`GraphBound`.  Its jitted program
+    comes from ``programs`` (the graph's :class:`ProgramTable`), keyed by
+    the algebra, ``max_rounds``, ``policy``, ``K``, ``harvest_mask``,
+    ``fused``, ``frontier_mode`` and the graph's static fields, so a
+    bound with the constants of an earlier one runs its traced program;
+    ``programs=None`` builds a program of the bound's own.
     """
     from repro.core.scheduler import POLICIES
     if policy not in POLICIES:
@@ -628,6 +689,20 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
                          f"one of {POLICIES}")
     if K < 1:
         raise ValueError(f"megastep chunk size K must be >= 1, got {K}")
+    key = (_algebra_key(algebra), max_rounds, policy, K, harvest_mask,
+           fused, frontier_mode, dg.num_parts, dg.block_size, dg.dmax,
+           dg.ell_width)
+    program, reused = (programs or ProgramTable()).get(
+        key, functools.partial(_build_megastep, dg, algebra, max_rounds,
+                               policy, K, harvest_mask, fused,
+                               frontier_mode))
+    return GraphBound(program.fn, dg, program=program, reused=reused)
+
+
+def _build_megastep(dg, algebra: VisitAlgebra, max_rounds: int, policy: str,
+                    K: int, harvest_mask: bool, fused: bool,
+                    frontier_mode: str) -> _Traced:
+    """The jitted megastep of :func:`make_megastep`, with its trace count."""
     P = dg.num_parts
     if fused:
         # the dispatch-table wiring of the three visit kernels (the
@@ -672,10 +747,12 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
         def meta(st: VisitState):
             return st.prio, st.stamp, st.ops_count
 
+    program = _Traced()
+
     @jax.jit
     def megastep(g, state: VisitState, counter: jax.Array,
                  limit: jax.Array, key: jax.Array):
-        bound.traces += 1               # trace time only: counts retraces
+        program.traces += 1             # trace time only: counts retraces
         visit = visit_of(g)
         limit_k = jnp.minimum(jnp.int32(limit), jnp.int32(K))
 
@@ -720,8 +797,8 @@ def make_megastep(dg, algebra: VisitAlgebra, max_rounds: int,
                                  eq_lo=lo, visit_counts=counts, order=order,
                                  lane_pending=lane_pending, key=key)
 
-    bound = GraphBound(megastep, dg)
-    return bound
+    program.fn = megastep
+    return program
 
 
 def harvest_edges(eq_hi: np.ndarray, eq_lo: np.ndarray) -> np.ndarray:

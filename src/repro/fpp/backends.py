@@ -164,7 +164,8 @@ def run_query(backend: str, kind: str, bg: BlockGraph, sources: np.ndarray,
                             use_pallas=use_pallas, fused=fused,
                             frontier_mode=frontier_mode,
                             hop_budget=k, hop_stride=hop_stride)
-            spans.note(sp, relax_width=eng.relax_width)
+            spans.note(sp, relax_width=eng.relax_width,
+                       program_reused=int(eng.program_reused))
         res = eng.run(sources, max_visits=max_visits, support=support)
         return _normalize(res.values, res.residual, res.edges_processed, {
             "visits": res.stats.visits, "rounds": res.stats.rounds,

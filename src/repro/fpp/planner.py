@@ -500,6 +500,4 @@ def default_yield_config(kind: str, bg) -> YieldConfig:
         # be the wrong scale (0 for cc, the hop stride for kreach) — run
         # the full-window fixpoint instead
         return YieldConfig()
-    wmax = float(np.nanmax(np.where(np.isfinite(bg.blocks), bg.blocks,
-                                    np.nan)))
-    return YieldConfig(delta=default_delta(wmax))
+    return YieldConfig(delta=default_delta(bg.weight_max))

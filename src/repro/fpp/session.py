@@ -216,7 +216,7 @@ class FPPSession:
                 weights=WEIGHT_VARIANTS.get(kind, "natural"))
             with spans.span(spans.ENGINE):
                 # the Δ-window is a constant of the engine's programs; its
-                # default scans the whole host block store
+                # default reads the graph's weight maximum
                 yc = (yield_config if yield_config is not None else
                       (p.yield_config
                        or _planner.default_yield_config(kind, bg)))

@@ -99,7 +99,7 @@ def build_stream_megastep(engine: FPPEngine, schedule: str) -> Callable:
     return _visit.make_megastep(
         engine.dg, engine.algebra, engine.max_rounds, policy=schedule,
         K=engine.k_visits, harvest_mask=True, fused=engine.fused,
-        frontier_mode=engine.frontier_mode)
+        frontier_mode=engine.frontier_mode, programs=engine.programs)
 
 
 @dataclasses.dataclass

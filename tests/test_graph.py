@@ -1,4 +1,6 @@
 """Unit + property tests for CSR / BlockGraph containers."""
+import warnings
+
 import numpy as np
 import pytest
 
@@ -8,6 +10,9 @@ except ImportError:        # degrade: unit tests run, property tests skip
     given = None
 
 from repro.core.graph import BlockGraph, CSRGraph, vmem_block_size
+from repro.core.queries import reweight
+from repro.core.yielding import default_delta
+from repro.fpp.planner import default_yield_config
 from repro.graphs.generators import erdos_renyi, grid2d, rmat, watts_strogatz
 
 
@@ -69,6 +74,43 @@ def test_blockgraph_diagonal_always_present():
     for p in range(bg.num_parts):
         k = bg.diag_blk[p]
         assert bg.blk_src[k] == p and bg.blk_dst[k] == p
+
+
+def _scanned_weight_max(bg: BlockGraph) -> float:
+    """The Δ-window's scale read the way it was before the BlockGraph kept
+    it: a scan of the whole block store."""
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)  # all-NaN: no edge
+        return float(np.nanmax(np.where(np.isfinite(bg.blocks), bg.blocks,
+                                        np.nan)))
+
+
+def _heavy_duplicate() -> CSRGraph:
+    # two parallel 0 -> 1 edges: the heavier is the largest weight given,
+    # but the store folds the pair to the lighter, so the maximum is 5
+    return CSRGraph.from_edges(6, [0, 0, 2, 3], [1, 1, 3, 4],
+                               [2.0, 9.0, 5.0, 1.5], dedup=False)
+
+
+WEIGHT_MAX_GRAPHS = {
+    "grid2d": lambda: grid2d(7, 9, seed=1),
+    "heavy-duplicate": _heavy_duplicate,
+    **{f"reweight-{v}": (lambda v=v: reweight(grid2d(6, 6, seed=2), v))
+       for v in ("natural", "unit", "zero", "shift")},
+    "edgeless": lambda: CSRGraph.from_edges(5, [], []),
+}
+
+
+@pytest.mark.parametrize("name", list(WEIGHT_MAX_GRAPHS))
+def test_weight_max_is_the_block_store_scan_bit_for_bit(name):
+    g = WEIGHT_MAX_GRAPHS[name]()
+    bg = BlockGraph.from_csr(g, 4)
+    scanned = _scanned_weight_max(bg)
+    assert (np.float64(bg.weight_max).tobytes()
+            == np.float64(scanned).tobytes())
+    assert default_yield_config("sssp", bg).delta == default_delta(scanned)
+    if name == "heavy-duplicate":
+        assert bg.weight_max == 5.0 < g.weights.max()
 
 
 def test_vmem_block_size_monotone():

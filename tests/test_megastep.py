@@ -13,8 +13,14 @@ What these tests pin:
     same visit order and same values);
   * ``FPPEngine.run`` performs O(visits/K) host synchronizations;
   * a staggered streaming run through chunked megastep pumps still equals
-    the one-shot run of the union (admission/harvest at chunk boundaries).
+    the one-shot run of the union (admission/harvest at chunk boundaries);
+  * an engine with the constants of an earlier one over the same graph
+    runs the program that one traced, and every constant the trace closes
+    over keys a program of its own.
 """
+import sys
+import threading
+
 import numpy as np
 import pytest
 
@@ -26,6 +32,7 @@ from repro.core import visit as V  # noqa: E402
 from repro.core.engine import FPPEngine  # noqa: E402
 from repro.core.partition import partition  # noqa: E402
 from repro.core.scheduler import POLICIES, PartitionScheduler  # noqa: E402
+from repro.core.yielding import YieldConfig  # noqa: E402
 from repro.fpp import FPPSession  # noqa: E402
 from repro.graphs.generators import grid2d, rmat  # noqa: E402
 
@@ -289,3 +296,126 @@ def test_device_made_state_is_the_host_made_state_bit_for_bit(mode, lanes):
         got = np.asarray(got)
         assert got.dtype == want.dtype and got.shape == want.shape
         assert got.tobytes() == want.tobytes()
+
+
+# ---------------------------------------------------------------------------
+# one traced program per graph and constants
+
+
+def _same_bits(a, b):
+    assert a.stats.visits == b.stats.visits
+    np.testing.assert_array_equal(a.edges_processed, b.edges_processed)
+    for x, y in ((a.values, b.values), (a.residual, b.residual)):
+        assert (x is None) == (y is None)
+        if x is not None:
+            assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
+
+
+@pytest.mark.parametrize("kind", ["sssp", "ppr"])
+def test_second_run_of_a_plan_reuses_its_traced_megastep(kind):
+    g = grid2d(12, 12, seed=3)
+    srcs = np.array([0, 50, 100, 143])
+    sess = FPPSession(g).plan(num_queries=4, block_size=32)
+    first = sess.run(kind, srcs)
+    again = sess.run(kind, srcs)
+    fresh = FPPSession(g).plan(num_queries=4, block_size=32).run(kind, srcs)
+    assert first.stats["megastep_traces"] == 1
+    assert again.stats["megastep_traces"] == 0
+    assert fresh.stats["megastep_traces"] == 1
+    for res in (again, fresh):
+        np.testing.assert_array_equal(res.edges_processed,
+                                      first.edges_processed)
+        assert res.values.tobytes() == first.values.tobytes()
+
+
+_BASE = {"minplus": dict(mode="minplus", yield_config=YieldConfig(delta=2.0),
+                         k_visits=8),
+         "push": dict(mode="push", alpha=0.15, eps=1e-3, k_visits=8)}
+
+#: (mode, the engine arguments that change one constant of the program)
+_ONE_CONSTANT = {
+    "delta": ("minplus", dict(yield_config=YieldConfig(delta=3.0))),
+    "max_rounds": ("minplus",
+                   dict(yield_config=YieldConfig(delta=2.0, max_rounds=3))),
+    "k_visits": ("minplus", dict(k_visits=16)),
+    "schedule": ("minplus", dict(schedule="fifo")),
+    "fused": ("minplus", dict(fused=True)),
+    "harvest_mask": ("minplus", {}),
+    "alpha": ("push", dict(alpha=0.2)),
+    "eps": ("push", dict(eps=2e-3)),
+}
+
+
+@pytest.mark.parametrize("constant", list(_ONE_CONSTANT))
+def test_each_program_constant_traces_a_program_of_its_own(constant):
+    """An engine that differs from a warm one in one constant the trace
+    closes over traces exactly once, and computes the bits of the same
+    engine built over a fresh graph (a table of its own)."""
+    mode, change = _ONE_CONSTANT[constant]
+    if mode == "minplus":
+        g, bg, _, srcs = _minplus_setup()
+    else:
+        g, bg, _, _, srcs = _push_setup()
+    fresh_bg, _ = partition(g, bg.block_size, method="bfs")
+    warm = FPPEngine(bg, num_queries=len(srcs), **_BASE[mode])
+    assert warm.run(srcs).stats.megastep_traces == 1
+    kw = {**_BASE[mode], **change}
+    eng = FPPEngine(bg, num_queries=len(srcs), **kw)
+    fresh = FPPEngine(fresh_bg, num_queries=len(srcs), **kw)
+    if constant == "harvest_mask":
+        for e in (eng, fresh):
+            e._megastep = V.make_megastep(
+                e.dg, e.algebra, e.max_rounds, K=e.k_visits,
+                harvest_mask=True, programs=e.programs)
+    assert not eng._megastep.reused
+    got = eng.run(srcs)
+    assert got.stats.megastep_traces == 1
+    _same_bits(got, fresh.run(srcs))
+    assert eng.run(srcs).stats.megastep_traces == 0
+
+
+def test_a_wrapped_make_megastep_sees_every_chunk_of_a_reused_program():
+    """The benchmark records each chunk's visit counts by wrapping
+    ``visit.make_megastep``; a run that reuses the warm program still
+    builds its bound through it."""
+    from chipbench.traffic.batch import visit_counts
+    g = grid2d(24, 24, seed=1)
+    srcs = np.array([0, 100, 300, 575])
+    sess = FPPSession(g).plan(num_queries=4, block_size=16)
+    sess.run("sssp", srcs)
+    sink = []
+    with visit_counts(sink):
+        res = sess.run("sssp", srcs)
+    assert res.stats["megastep_traces"] == 0
+    assert len(sink) == res.stats["host_syncs"] > 1
+    np.testing.assert_array_equal(
+        np.sum([np.asarray(c) for c in sink], axis=0),
+        res.stats["visit_counts"])
+
+
+def test_threads_building_one_megastep_share_one_program():
+    """The serving pools warm their programs on threads of their own:
+    bounds built at once over one table build one program and share it."""
+    _, bg, _, srcs = _minplus_setup()
+    eng = FPPEngine(bg, num_queries=len(srcs), k_visits=4)
+    bounds, start = [], threading.Barrier(16)
+
+    def build():
+        start.wait(timeout=60)
+        bounds.append(V.make_megastep(eng.dg, eng.algebra, eng.max_rounds,
+                                      K=4, harvest_mask=True,
+                                      programs=eng.programs))
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=build) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads) and len(bounds) == 16
+    assert len({id(b.program) for b in bounds}) == 1
+    assert sum(not b.reused for b in bounds) == 1
+    assert bounds[0].program is not eng._megastep.program
